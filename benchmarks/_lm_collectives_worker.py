@@ -6,10 +6,11 @@ import re
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.comm import compressed_ring_reduce_scatter, ring_allgather, ring_reduce_scatter
-from repro.compat import make_mesh, shard_map
+from repro.compat import make_mesh
 
 
 def _mesh():

@@ -39,8 +39,12 @@ def emit(name: str, us_per_call: float, derived: str = ""):
 
 
 def run_worker(module: str, args: list, devices: int = 8, timeout: int = 1200) -> str:
-    """Run a benchmark worker in a subprocess with N host devices."""
+    """Run a benchmark worker in a subprocess with N host devices.
+
+    Workers are host virtual-device runs by design: they are pinned to the
+    CPU backend and never contend with the parent for an accelerator."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     proc = subprocess.run(

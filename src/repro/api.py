@@ -46,6 +46,7 @@ import numpy as np
 
 from repro.core.count_engine import (
     build_counting_plan,
+    build_edge_plan,
     build_multi_counting_plan,
     colorful_map_count,
     colorful_map_count_checked,
@@ -305,6 +306,7 @@ class Counter:
         self.backend = backend
         self.plan_opts = plan_opts
         self._plan = None
+        self._edge_plan = None  # single backend: shared by all its plans
         self._mesh = None
         self._num_shards: Optional[int] = None
         self._fn_kw: Dict[str, Any] = {}
@@ -394,9 +396,20 @@ class Counter:
     def k(self) -> int:
         return self.tree.n
 
+    def _shared_edge_plan(self):
+        """The graph's neighbor-sum layout, built once for every plan of
+        this Counter (its template and each ``estimate_many`` family)."""
+        if self._edge_plan is None:
+            keys = ("spmm_kind", "tile_size", "block_size")
+            opts = {k: v for k, v in self.plan_opts.items() if k in keys}
+            self._edge_plan = build_edge_plan(self.graph, **opts)
+        return self._edge_plan
+
     def _build_single(self):
         if self._plan is None:
-            self._plan = build_counting_plan(self.graph, self.tree, **self.plan_opts)
+            self._plan = build_counting_plan(
+                self.graph, self.tree, spmm_plan=self._shared_edge_plan(), **self.plan_opts
+            )
         return self._plan
 
     def _dist_ctx(self):
@@ -435,14 +448,20 @@ class Counter:
         self._mesh = mesh
         self._num_shards = num_shards
 
+    def _place(self, plan):
+        """Lay a distributed plan's shards out on this Counter's mesh, once."""
+        from repro.core.distributed import place_plan
+
+        return place_plan(plan, self._mesh, self._fn_kw.get("data_axis", "data"))
+
     def _build_distributed(self):
         if self._plan is None:
             from repro.core.distributed import build_distributed_plan
 
             self._dist_ctx()
-            self._plan = build_distributed_plan(
+            self._plan = self._place(build_distributed_plan(
                 self.graph, self.tree, self._num_shards, **self._plan_kw
-            )
+            ))
         return self._plan
 
     def _iter_size(self) -> int:
@@ -628,14 +647,18 @@ class Counter:
             return st
         if self.backend == "single":
             keep = {k: v for k, v in self.plan_opts.items() if k != "root"}
-            plan = build_multi_counting_plan(self.graph, trees, **keep)
+            plan = build_multi_counting_plan(
+                self.graph, trees, spmm_plan=self._shared_edge_plan(), **keep
+            )
             st = {"plan": plan, "sample_fn": multi_sample_fn(plan), "coloring_fn": None}
         else:
             from repro.core.distributed import build_distributed_plan
 
             self._dist_ctx()
             plan_kw = {k: v for k, v in self._plan_kw.items() if k != "root"}
-            plan = build_distributed_plan(self.graph, trees, self._num_shards, **plan_kw)
+            plan = self._place(
+                build_distributed_plan(self.graph, trees, self._num_shards, **plan_kw)
+            )
             st = {"plan": plan, "sample_fn": None, "coloring_fn": None}
         self._families[trees] = st
         return st

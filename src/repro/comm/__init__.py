@@ -22,6 +22,7 @@ from .adaptive import (  # noqa: F401
     HockneyModel,
     V5E_ICI,
     V5E_DCI,
+    assumed_model,
     calibrate,
     choose_mode,
     choose_mode_full,

@@ -22,12 +22,14 @@ from __future__ import annotations
 import dataclasses
 import math
 import time
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 __all__ = [
     "HockneyModel",
     "V5E_ICI",
     "V5E_DCI",
+    "ASSUMED_LINKS",
+    "assumed_model",
     "overlap_ratio",
     "pipeline_cost",
     "fused_cost",
@@ -51,6 +53,26 @@ class HockneyModel:
 # ICI collective latencies (~5 us per hop).
 V5E_ICI = HockneyModel(alpha=5e-6, beta=1.0 / 50e9, flops_per_s=197e12)
 V5E_DCI = HockneyModel(alpha=20e-6, beta=1.0 / 25e9, flops_per_s=197e12)
+
+#: the router's assumed link model, by ``device_kind`` (``adaptive="model"``)
+ASSUMED_LINKS: Dict[str, HockneyModel] = {
+    "TPU v5 lite": V5E_ICI,  # the device_kind JAX reports for a v5e
+    # test-only assumption: host virtual devices have no link to model;
+    # CPU runs route as a v5e mesh would, so their choices match the chip's
+    "cpu": V5E_ICI,
+}
+
+
+def assumed_model(device_kind: str) -> HockneyModel:
+    """The assumed link model of ``device_kind``; an unknown kind raises
+    rather than borrowing another chip's constants."""
+    try:
+        return ASSUMED_LINKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no assumed link model for device kind {device_kind!r} "
+            f"(known: {sorted(ASSUMED_LINKS)}); route with adaptive='measured'"
+        ) from None
 
 
 def overlap_ratio(comp_chunk_s: float, comm_chunk_s: float) -> float:
@@ -166,7 +188,7 @@ def calibrate(
     *,
     payload_bytes: Tuple[int, ...] = (1 << 16, 1 << 19, 1 << 22),
     repeats: int = 3,
-    base: HockneyModel = V5E_ICI,
+    base: Optional[HockneyModel] = None,
 ) -> HockneyModel:
     """Fit alpha/beta (and a matmul flop rate) from a measured probe.
 
@@ -175,17 +197,18 @@ def calibrate(
     single [n, n] matmul for ``flops_per_s``.  Runs once per
     ``(platform, device kind, P)`` — results are cached for the process.
     On a single-device axis the assumed ``base`` model is returned
-    unchanged (there is no link to measure).
+    unchanged (there is no link to measure); ``base`` defaults to the
+    mesh device's :func:`assumed_model`.
     """
     import jax
     import jax.numpy as jnp
 
-    from repro.compat import shard_map
-
+    dev = mesh.devices.flat[0]
+    if base is None:
+        base = assumed_model(dev.device_kind)
     P = int(mesh.shape[data_axis])
     if P <= 1:
         return base
-    dev = jax.devices()[0]
     cache_key = (dev.platform, getattr(dev, "device_kind", ""), P, payload_bytes)
     hit = _CALIBRATION_CACHE.get(cache_key)
     if hit is not None:
@@ -202,7 +225,7 @@ def calibrate(
             return jax.lax.ppermute(x, data_axis, perm)
 
         fn = jax.jit(
-            shard_map(
+            jax.shard_map(
                 shift,
                 mesh=mesh,
                 in_specs=PS(data_axis),

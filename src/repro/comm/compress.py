@@ -26,7 +26,6 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
 
 __all__ = [
     "WIRE_DTYPES",
@@ -163,7 +162,7 @@ def compressed_ring_reduce_scatter(x: jax.Array, axis_name: str, *, block: int =
     Output: this device's fully reduced chunk (fp32).  Chunk sizes must be a
     multiple of ``block`` elements.
     """
-    P = axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     p = jax.lax.axis_index(axis_name)
     chunk_shape = x.shape[1:]
     total = 1

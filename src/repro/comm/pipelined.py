@@ -21,7 +21,6 @@ from typing import Callable
 
 import jax
 
-from repro.compat import axis_size
 
 __all__ = ["grouped_exchange", "fused_exchange"]
 
@@ -44,7 +43,7 @@ def fused_exchange(
     before compute starts (the paper's peak-memory pathology, kept
     deliberately for the Naive baseline).
     """
-    P = axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     received = jax.lax.all_to_all(chunks, axis_name, split_axis=0, concat_axis=0)
     p = jax.lax.axis_index(axis_name)
     acc = init
@@ -72,7 +71,7 @@ def grouped_exchange(
     instead of P (Eq. 12); each group's sends overlap the previous group's
     consumes (Eq. 13/14).
     """
-    P = axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     p = jax.lax.axis_index(axis_name)
     g = max(1, min(group_factor, P - 1))
 

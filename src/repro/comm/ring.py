@@ -18,7 +18,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size, pvary_like
+from repro.compat import pvary_like
 
 __all__ = ["ring_allgather", "ring_allgather_overlap", "ring_reduce_scatter"]
 
@@ -31,7 +31,7 @@ def ring_allgather(x: jax.Array, axis_name: str, *, tiled: bool = False) -> jax.
     """All-gather via P-1 ring hops (reference; prefer lax.all_gather when
     no overlap is wanted — this exists to bound peak memory per step in
     callers that consume chunks immediately)."""
-    P = axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     p = jax.lax.axis_index(axis_name)
 
     def body(w, carry):
@@ -66,7 +66,7 @@ def ring_allgather_overlap(
     transfer overlaps the combine (paper Fig. 3 pipeline; ratio rho_w of
     Eq. 14 is realized by XLA async scheduling).
     """
-    P = axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     p = jax.lax.axis_index(axis_name)
 
     def body(w, carry):
@@ -93,7 +93,7 @@ def ring_reduce_scatter(x: jax.Array, axis_name: str, *, chunk_axis: int = 0) ->
     """
     if chunk_axis != 0:
         x = jnp.moveaxis(x, chunk_axis, 0)
-    P = axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     p = jax.lax.axis_index(axis_name)
 
     def body(w, buf):

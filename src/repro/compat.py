@@ -1,38 +1,18 @@
-"""Version-compat shims for the JAX APIs this repo uses across releases.
-
-The repo targets the newest stable JAX but must degrade onto the versions
-actually baked into CI / test containers.  Two shims live here:
-
-``shard_map``
-    ``jax.shard_map`` (new spelling, ``check_vma`` kwarg) vs
-    ``jax.experimental.shard_map.shard_map`` (old spelling, ``check_rep``).
+"""Two small JAX helpers shared by the mesh and shard_map code.
 
 ``make_mesh``
-    ``jax.make_mesh(..., axis_types=(AxisType.Auto, ...))`` vs releases
-    that predate ``jax.sharding.AxisType`` (where plain ``make_mesh`` has
-    the same auto-sharding semantics).
+    ``jax.make_mesh`` with every axis ``AxisType.Auto`` (sharding by
+    propagation, which the shard_map engines expect).
 
 ``pvary_like``
-    Varying-manual-axes promotion for shard_map loop carries on releases
-    with the ``vma`` type system; a no-op on releases without it.
+    Varying-manual-axes promotion for shard_map loop carries.
 """
 
 from __future__ import annotations
 
 import jax
 
-__all__ = ["shard_map", "make_mesh", "axis_size", "pvary_like"]
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` with the classic ``psum(1, axis)`` fallback.
-
-    Both return a static Python int for a named mesh axis inside a
-    shard_map/pmap region.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+__all__ = ["make_mesh", "pvary_like"]
 
 
 def pvary_like(val, like):
@@ -40,41 +20,16 @@ def pvary_like(val, like):
 
     Loop carries must have stable types under shard_map: a ``jnp.zeros``
     init is unvarying while permuted/sharded data is varying, so the init
-    must be pcast before entering a ``fori_loop``/``while_loop``.  On JAX
-    releases without the ``vma`` type system this is the identity.
+    must be pcast before entering a ``fori_loop``/``while_loop``/``scan``.
+    Outside a manual-axes context both sets are empty and this is the
+    identity.
     """
-    try:
-        need = set(jax.typeof(like).vma) - set(jax.typeof(val).vma)
-    except AttributeError:  # no vma tracking, or not in a manual-axes context
-        return val
+    need = set(jax.typeof(like).vma) - set(jax.typeof(val).vma)
     if need:
         val = jax.lax.pcast(val, tuple(sorted(need)), to="varying")
     return val
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, axis_names=None):
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kw = {}
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    if axis_names is not None:
-        # old spelling: `auto` is the complement of the manual axis set
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kw["auto"] = auto
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def make_mesh(shape, names):
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, names)
-    return jax.make_mesh(shape, names, axis_types=(axis_type.Auto,) * len(names))
+    auto = (jax.sharding.AxisType.Auto,) * len(names)
+    return jax.make_mesh(shape, names, axis_types=auto)

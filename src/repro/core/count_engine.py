@@ -82,6 +82,7 @@ __all__ = [
     "CountingPlan",
     "MultiCountingPlan",
     "build_counting_plan",
+    "build_edge_plan",
     "build_multi_counting_plan",
     "colorful_map_count",
     "colorful_map_count_checked",
@@ -92,6 +93,7 @@ __all__ = [
     "plan_sample_fn",
     "multi_sample_fn",
     "copy_scale",
+    "node_kernels",
 ]
 
 
@@ -164,7 +166,11 @@ class MultiCountingPlan:
         return tuple(copy_scale(self.k, t.n, a) for t, a in zip(self.templates, self.auts))
 
 
-def _build_spmm(g, spmm_kind, tile_size, block_size):
+def build_edge_plan(
+    g: Graph, *, spmm_kind: str = "edges", tile_size: int = 128, block_size: int = 128
+) -> ops.SpmmPlan:
+    """The graph's device-resident neighbor-sum layout.  Plans built with
+    ``spmm_plan=`` share one (every template on a resident graph)."""
     rows, cols = edge_list(g)
     return ops.build_spmm_plan(
         rows, cols, g.n, kind=spmm_kind, tile_size=tile_size, block_size=block_size
@@ -233,6 +239,7 @@ def build_counting_plan(
     density_threshold: float = DEFAULT_DENSITY_THRESHOLD,
     capacity_factor: float = DEFAULT_CAPACITY_FACTOR,
     probes: int = 2,
+    spmm_plan: Optional[ops.SpmmPlan] = None,
 ) -> CountingPlan:
     """``n_colors`` widens the color budget past the template size (used to
     compare single-template runs against a family counted with shared k).
@@ -254,7 +261,9 @@ def build_counting_plan(
     k = n_colors if n_colors is not None else tree.n
     if k < tree.n:
         raise ValueError(f"n_colors={k} is smaller than the template ({tree.n})")
-    plan = _build_spmm(g, spmm_kind, tile_size, block_size)
+    plan = spmm_plan or build_edge_plan(
+        g, spmm_kind=spmm_kind, tile_size=tile_size, block_size=block_size
+    )
     lane = _resolve_lane(lane, impl)
     combine, widths = build_node_tables(chain, k, lane=lane, x_dim=g.n if has_bags else None)
     compaction = _maybe_compaction(
@@ -302,12 +311,15 @@ def build_multi_counting_plan(
     density_threshold: float = DEFAULT_DENSITY_THRESHOLD,
     capacity_factor: float = DEFAULT_CAPACITY_FACTOR,
     probes: int = 2,
+    spmm_plan: Optional[ops.SpmmPlan] = None,
 ) -> MultiCountingPlan:
     """One plan for a whole template family: compile the set into a shared
     :class:`TemplateDag` and build each unique node's combine tables once."""
     dag = compile_templates(templates, n_colors=n_colors, roots=roots)
     has_bags = program_has_bags(dag)
-    plan = _build_spmm(g, spmm_kind, tile_size, block_size)
+    plan = spmm_plan or build_edge_plan(
+        g, spmm_kind=spmm_kind, tile_size=tile_size, block_size=block_size
+    )
     lane = _resolve_lane(lane, impl)
     combine, widths = build_node_tables(dag, dag.k, lane=lane, x_dim=g.n if has_bags else None)
     compaction = _maybe_compaction(
@@ -337,6 +349,27 @@ def build_multi_counting_plan(
         compaction=compaction,
         pin_adj=_build_pin_adj(g, plan.n_pad) if has_bags else None,
     )
+
+
+def node_kernels(plan) -> Dict[int, str]:
+    """Per internal node of the dense program, the implementation each of
+    its ops runs on this backend — the choice ``ops`` makes from the
+    node's table shapes (:func:`repro.kernels.ops.resolve_impl`)."""
+    program = plan.chain if isinstance(plan, CountingPlan) else plan.dag
+    sp = plan.spmm_plan
+    out: Dict[int, str] = {}
+    for i, nd in enumerate(program.nodes):
+        if nd.kind not in ("combine", "bag_combine"):
+            continue
+        tbl = plan.combine[i]
+        a, b = plan.widths[nd.left], plan.widths[nd.right]
+        if nd.kind == "combine" and plan.fuse and sp.slab_dst is not None:
+            out[i] = "fused=" + ops.fused_impl(a, b, tbl, plan.impl, sp.n_pad, sp.row_tile)
+            continue
+        x = plan.n if nd.kind == "bag_combine" else 1  # combine runs per x block
+        out[i] = (f"spmm={ops.spmm_impl(sp, b, plan.impl)} "
+                  f"combine={ops.combine_impl(a // x, b // x, tbl, plan.impl)}")
+    return out
 
 
 def _program_counts(plan, program, coloring: jax.Array, *, checked=False):
@@ -515,6 +548,19 @@ def _checked_fallback(compact_fn, make_dense):
     return f
 
 
+def _jit_counter(plan, f):
+    """``jax.jit(f)`` over ``key`` with the plan's edge layout passed as an
+    argument (never a closed-over constant, see :class:`ops.SpmmPlan`).
+    The returned callable also exposes ``lower(key)`` for memory analysis."""
+    jf = jax.jit(lambda sp, key: f(dataclasses.replace(plan, spmm_plan=sp), key))
+
+    def call(key: jax.Array):
+        return jf(plan.spmm_plan, key)
+
+    call.lower = lambda key: jf.lower(plan.spmm_plan, key)
+    return call
+
+
 def count_fn(plan: CountingPlan, batch: Optional[int] = None):
     """Jitted per-iteration counter.
 
@@ -536,22 +582,22 @@ def count_fn(plan: CountingPlan, batch: Optional[int] = None):
 
     if batch is None:
 
-        def f(key: jax.Array):
-            coloring = jax.random.randint(key, (plan.n_pad,), 0, plan.k, dtype=jnp.int32)
-            maps, ok = count1(plan, coloring)
-            return (maps, maps * plan.scale) if ok is None else (maps, maps * plan.scale, ok)
+        def f(p, key: jax.Array):
+            coloring = jax.random.randint(key, (p.n_pad,), 0, p.k, dtype=jnp.int32)
+            maps, ok = count1(p, coloring)
+            return (maps, maps * p.scale) if ok is None else (maps, maps * p.scale, ok)
 
     else:
 
-        def f(key: jax.Array):
-            colorings = jax.random.randint(key, (batch, plan.n_pad), 0, plan.k, dtype=jnp.int32)
-            maps, ok = jax.vmap(lambda c: count1(plan, c))(colorings)
-            return (maps, maps * plan.scale) if not compact else (maps, maps * plan.scale, ok)
+        def f(p, key: jax.Array):
+            colorings = jax.random.randint(key, (batch, p.n_pad), 0, p.k, dtype=jnp.int32)
+            maps, ok = jax.vmap(lambda c: count1(p, c))(colorings)
+            return (maps, maps * p.scale) if not compact else (maps, maps * p.scale, ok)
 
     if not compact:
-        return jax.jit(f)
+        return _jit_counter(plan, f)
     dense_plan = dataclasses.replace(plan, compaction=None)
-    return _checked_fallback(jax.jit(f), lambda: count_fn(dense_plan, batch))
+    return _checked_fallback(_jit_counter(plan, f), lambda: count_fn(dense_plan, batch))
 
 
 def count_fn_many(plan: MultiCountingPlan, batch: Optional[int] = None):
@@ -560,7 +606,7 @@ def count_fn_many(plan: MultiCountingPlan, batch: Optional[int] = None):
     as :func:`count_fn` with ``n_colors=plan.k``, so a family run and a
     per-template run from the same key see identical colorings.  Compacted
     plans fall back to the dense twin on overflow, like :func:`count_fn`."""
-    scales = jnp.asarray(plan.scales)
+    scales = np.asarray(plan.scales, np.float32)
     compact = plan.compaction is not None and plan.compaction.enabled
     count1 = colorful_map_count_many_checked if compact else (
         lambda p, c: (colorful_map_count_many(p, c), None)
@@ -568,25 +614,25 @@ def count_fn_many(plan: MultiCountingPlan, batch: Optional[int] = None):
 
     if batch is None:
 
-        def f(key: jax.Array):
-            coloring = jax.random.randint(key, (plan.n_pad,), 0, plan.k, dtype=jnp.int32)
-            maps, ok = count1(plan, coloring)
+        def f(p, key: jax.Array):
+            coloring = jax.random.randint(key, (p.n_pad,), 0, p.k, dtype=jnp.int32)
+            maps, ok = count1(p, coloring)
             return (maps, maps * scales) if ok is None else (maps, maps * scales, ok)
 
     else:
 
-        def f(key: jax.Array):
-            colorings = jax.random.randint(key, (batch, plan.n_pad), 0, plan.k, dtype=jnp.int32)
-            maps, ok = jax.vmap(lambda c: count1(plan, c))(colorings)
+        def f(p, key: jax.Array):
+            colorings = jax.random.randint(key, (batch, p.n_pad), 0, p.k, dtype=jnp.int32)
+            maps, ok = jax.vmap(lambda c: count1(p, c))(colorings)
             return (maps, maps * scales[None, :]) if not compact else (
                 maps, maps * scales[None, :], ok
             )
 
     if not compact:
-        return jax.jit(f)
+        return _jit_counter(plan, f)
     dense_plan = dataclasses.replace(plan, compaction=None)
     return _checked_fallback(
-        jax.jit(f), lambda: count_fn_many(dense_plan, batch)
+        _jit_counter(plan, f), lambda: count_fn_many(dense_plan, batch)
     )
 
 

@@ -94,13 +94,14 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.comm import (
-    V5E_ICI,
     WIRE_DTYPES,
     WIRE_ESCALATION,
     HockneyModel,
+    assumed_model,
     calibrate,
     choose_mode_full,
     grouped_exchange,
@@ -110,7 +111,7 @@ from repro.comm import (
     ring_allgather_overlap,
     widen,
 )
-from repro.compat import pvary_like, shard_map
+from repro.compat import pvary_like
 from repro.kernels import ops
 from repro.testing import faults
 from .count_engine import copy_scale
@@ -150,6 +151,7 @@ from .templates import (
 __all__ = [
     "DistributedPlan",
     "build_distributed_plan",
+    "place_plan",
     "make_count_fn",
     "keyed_sample_fn",
     "plan_route_report",
@@ -177,9 +179,10 @@ class DistributedPlan:
     auts: Tuple[int, ...]  # per-template |Aut|
     combine: Dict[int, ops.CombineTables]
     widths: Dict[int, int]
-    # host-global arrays; sharded over dim 0 by the data axis.  The bucket
-    # arrays are O(E + tiles): tiles are addressed via CSR offsets, never
-    # padded to the largest bucket.
+    # host-global arrays; sharded over dim 0 by the data axis.
+    # build_distributed_plan leaves them on the host (numpy); :func:`place_plan` puts shard p's
+    # slice on device p.  The bucket arrays are O(E + tiles): tiles are
+    # addressed via CSR offsets, never padded to the largest bucket.
     tile_dst: jax.Array  # [P, T, tile] int32 local dst row (pad: shard_size)
     tile_src_local: jax.Array  # [P, T, tile] int32 src-shard-local row (ring)
     tile_src_compact: jax.Array  # [P, T, tile] int32 request slot (pipeline)
@@ -235,6 +238,28 @@ class DistributedPlan:
         if self.pin_adj is not None:
             base = base + (self.pin_adj,)
         return base
+
+
+_ARRAY_FIELDS = (
+    "tile_dst", "tile_src_local", "tile_src_compact", "tile_off", "send_idx",
+    "a2a_slab_dst", "a2a_slab_cols", "pin_adj",
+)
+
+
+def place_plan(
+    plan: DistributedPlan, mesh: jax.sharding.Mesh, data_axis: str = "data"
+) -> DistributedPlan:
+    """The plan with every per-shard array laid out over ``data_axis`` of
+    ``mesh``: shard p's slice lives on the devices of data index p (and is
+    replicated over any other mesh axis).  Arrays already placed so are
+    left where they are."""
+    sharding = NamedSharding(mesh, P(data_axis))
+    placed = {
+        name: jax.device_put(getattr(plan, name), sharding)
+        for name in _ARRAY_FIELDS
+        if getattr(plan, name) is not None
+    }
+    return dataclasses.replace(plan, **placed)
 
 
 def _resolve_program(tree, root: int, n_colors: Optional[int]):
@@ -377,7 +402,7 @@ def build_distributed_plan(
         # holding global vertex v, column x is A[v, x] (pad rows all-zero)
         pa = np.zeros((Pn, n_loc_pad, g.n), np.float32)
         pa[p_of, rows - p_of * shard_size, cols] = 1.0
-        pin_adj = jnp.asarray(pa)
+        pin_adj = pa
 
     compaction = None
     if compact and not has_bags:
@@ -411,13 +436,13 @@ def build_distributed_plan(
         auts=tuple(automorphism_count(t) for t in templates),
         combine=combine,
         widths=widths,
-        tile_dst=jnp.asarray(tile_dst),
-        tile_src_local=jnp.asarray(tile_src_local),
-        tile_src_compact=jnp.asarray(tile_src_compact),
-        tile_off=jnp.asarray(tile_off),
-        send_idx=jnp.asarray(send_idx),
-        a2a_slab_dst=jnp.asarray(a2a_slab_dst),
-        a2a_slab_cols=jnp.asarray(a2a_slab_cols),
+        tile_dst=tile_dst,
+        tile_src_local=tile_src_local,
+        tile_src_compact=tile_src_compact,
+        tile_off=tile_off,
+        send_idx=send_idx,
+        a2a_slab_dst=a2a_slab_dst,
+        a2a_slab_cols=a2a_slab_cols,
         bucket_counts=counts,
         compaction=compaction,
         pin_adj=pin_adj,
@@ -610,7 +635,7 @@ def plan_route_report(
     group_factor: int = 1,
     wire_dtype: str = "float32",
     adaptive: str = "model",
-    hockney: HockneyModel = V5E_ICI,
+    hockney: Optional[HockneyModel] = None,
     mesh: Optional[jax.sharding.Mesh] = None,
     data_axis: str = "data",
 ) -> dict:
@@ -618,12 +643,17 @@ def plan_route_report(
 
     With ``adaptive="measured"`` and a mesh, the Hockney constants come
     from the one-shot calibration probe (``comm.adaptive.calibrate``);
-    otherwise the assumed ``hockney`` model is used.  Per internal node
+    otherwise the assumed ``hockney`` model is used (by default the
+    :func:`~repro.comm.adaptive.assumed_model` of the mesh's device kind,
+    or of the default device without a mesh).  Per internal node
     the report carries the compacted+compressed byte counts of both wire
     layouts, the consuming flops, the modeled cost of each schedule, and
     the mode the router picks — the launcher plan report and the dry-run
     cells surface this verbatim.
     """
+    if hockney is None:
+        dev = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+        hockney = assumed_model(dev.device_kind)
     model = hockney
     calibrated = False
     if adaptive == "measured" and mesh is not None:
@@ -671,7 +701,7 @@ def make_count_fn(
     group_factor: int = 1,
     impl: str = "xla",
     fuse: bool = False,
-    hockney: HockneyModel = V5E_ICI,
+    hockney: Optional[HockneyModel] = None,
     wire_dtype: str = "float32",
     adaptive: str = "model",
     return_raw: bool = False,
@@ -734,7 +764,9 @@ def make_count_fn(
     ``adaptive="measured"`` replaces the assumed Hockney constants with a
     one-shot calibration probe on this mesh (``comm.adaptive.calibrate``,
     cached per device kind and axis size) before the per-node routing
-    decision; ``"model"`` keeps the assumed ``hockney`` constants.
+    decision; ``"model"`` keeps the assumed ``hockney`` constants (by
+    default those of the mesh's device kind,
+    :func:`~repro.comm.adaptive.assumed_model`).
     """
     assert not (keyed and return_raw), "keyed and return_raw are exclusive"
     if wire_dtype not in WIRE_DTYPES:
@@ -747,6 +779,8 @@ def make_count_fn(
     axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     assert axis_sizes[data_axis] == Pn, (axis_sizes, Pn)
     wire_narrow = wire_dtype != "float32"
+    if hockney is None:
+        hockney = assumed_model(mesh.devices.flat[0].device_kind)
 
     if mode == "adaptive" and adaptive == "measured":
         hockney = calibrate(mesh, data_axis, base=hockney)
@@ -1212,8 +1246,6 @@ def make_count_fn(
     )
 
     if return_raw:
-        from jax.sharding import NamedSharding
-
         iter_size = 1
         for ax in (iter_axis if isinstance(iter_axis, tuple) else (iter_axis,)):
             if ax:
@@ -1226,13 +1258,22 @@ def make_count_fn(
         fn = jax.jit(mapped, in_shardings=in_shard)
         return fn, structs, in_shard
 
-    @jax.jit
-    def fj(data):
-        out = mapped(data, *plan.device_arrays)
+    # the plan arrays are arguments, sharded as the shard_map reads them: a
+    # closed-over array would be embedded in the program as a constant
+    arrays = place_plan(plan, mesh, data_axis).device_arrays
+    shard_args = jax.jit(
+        lambda data, *arrs: _unpack(mapped(data, *arrs)),
+        in_shardings=tuple(NamedSharding(mesh, s) for s in in_specs),
+    )
+
+    def _unpack(out):
         if speculative:
             counts, bad = out
             return (counts if plan.is_multi else counts[:, 0]), bad
         return out if plan.is_multi else out[:, 0]
+
+    def fj(data):
+        return shard_args(data, *arrays)
 
     if speculative:
         # speculative dispatch: the narrow/compact program reports

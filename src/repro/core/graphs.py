@@ -97,20 +97,16 @@ def from_edges(n: int, edges: np.ndarray, name: str = "") -> Graph:
     Self loops and duplicate edges are removed.
     """
     edges = np.asarray(edges, dtype=np.int64).reshape(-1, 2)
-    if edges.size:
-        edges = edges[edges[:, 0] != edges[:, 1]]
-        lo = np.minimum(edges[:, 0], edges[:, 1])
-        hi = np.maximum(edges[:, 0], edges[:, 1])
-        key = lo * n + hi
-        _, first = np.unique(key, return_index=True)
-        edges = np.stack([lo[first], hi[first]], axis=1)
-    both = np.concatenate([edges, edges[:, ::-1]], axis=0) if edges.size else edges
-    order = np.lexsort((both[:, 1], both[:, 0])) if both.size else np.array([], np.int64)
-    both = both[order] if both.size else both.reshape(0, 2)
-    counts = np.bincount(both[:, 0], minlength=n) if both.size else np.zeros(n, np.int64)
+    edges = edges[edges[:, 0] != edges[:, 1]]
+    lo = np.minimum(edges[:, 0], edges[:, 1])
+    hi = np.maximum(edges[:, 0], edges[:, 1])
+    # one int64 key per edge orders by (row, col); sorting keys is several
+    # times faster than a lexsort of pairs at Graph500 scale
+    key = np.unique(lo * n + hi)
+    both = np.sort(np.concatenate([key, (key % n) * n + key // n]))
     indptr = np.zeros(n + 1, np.int64)
-    np.cumsum(counts, out=indptr[1:])
-    indices = both[:, 1].astype(np.int32) if both.size else np.zeros(0, np.int32)
+    np.cumsum(np.bincount(both // n, minlength=n), out=indptr[1:])
+    indices = (both % n).astype(np.int32)
     return Graph(n, indptr, indices, name)
 
 

@@ -20,11 +20,12 @@ of their destinations — the paper's bounded neighbor-list task size ``s``.
 
 ``fused_count_pallas``
     grid = (row_blocks, slabs_per_block), slab axis innermost.  Each step
-    accumulates its slab into the resident ``[row_tile, B]`` scratch
-    (gather + one-hot MXU scatter matmul, as in the SpMM kernel); the
-    *last* slab of a row block runs the split-table contraction against the
-    resident ``left`` block and writes the ``[row_tile, S]`` output tile.
-    One pass over the edges, zero HBM traffic for ``M``.
+    adds its slab's source rows into the resident ``[row_tile, B]``
+    scratch (SMEM indices, one row per edge, as in the SpMM kernel); the
+    *last* slab of a row block runs the split-table contraction (the
+    combine kernel's one-hot selection matmuls) against the resident
+    ``left`` block and writes the ``[row_tile, S]`` output tile.  One pass
+    over the edges, zero HBM traffic for ``M``.
 
 ``fused_count_xla``
     The same schedule for non-TPU backends: ``lax.map`` (a sequential scan)
@@ -45,7 +46,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["fused_count_pallas", "fused_count_xla"]
+from .color_combine import split_contract
+from .spmm_edgetile import VMEM_LIMIT_BYTES, scatter_slab, slab_specs
+
+__all__ = ["fused_count_pallas", "fused_count_xla", "fused_vmem_bytes"]
+
+
+def fused_vmem_bytes(
+    table_rows: int, a: int, b: int, s_pad: int, j_pad: int, row_tile: int = 128
+) -> int:
+    """VMEM the fused kernel holds: the double-buffered resident source
+    table, ``left`` block, split tables and output tile, plus the
+    ``[row_tile, B]`` neighbor-sum scratch and the combine's one-hot
+    selection matrices (float32/int32)."""
+    buffered = table_rows * b + row_tile * (a + s_pad) + 2 * j_pad * s_pad
+    return 4 * (2 * buffered + row_tile * b + (a + b) * s_pad)
 
 
 def _fused_kernel(
@@ -67,34 +82,12 @@ def _fused_kernel(
     def _init():
         m_ref[...] = jnp.zeros_like(m_ref)
 
-    dst = dst_ref[0]  # [tile] int32 local dst row (-1 pad)
-    cols = col_ref[0]  # [tile] int32 global src row
-    tab = right_ref[...]  # [n_pad, B] resident
-    gathered = jnp.take(tab, cols, axis=0).astype(jnp.float32)  # [tile, B]
-    row_tile = m_ref.shape[0]
-    onehot = (
-        dst[:, None]
-        == jax.lax.broadcasted_iota(jnp.int32, (dst.shape[0], row_tile), 1)
-    ).astype(jnp.float32)
-    m_ref[...] += jax.lax.dot_general(
-        onehot, gathered, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    scatter_slab(dst_ref, col_ref, right_ref, m_ref)
 
     @pl.when(j == slabs_per_block - 1)
     def _combine():
-        lv = left_ref[...]  # [row_tile, A]
-        mv = m_ref[...]  # [row_tile, B] — the only life M ever has
-
-        def body(jj, acc):
-            i1 = idx1_ref[jj, :]  # [S] int32 — sublane-axis dynamic slice
-            i2 = idx2_ref[jj, :]
-            g1 = jnp.take(lv, i1, axis=1)  # [row_tile, S] lane gather
-            g2 = jnp.take(mv, i2, axis=1)
-            return acc + g1 * g2
-
-        acc0 = jnp.zeros(out_ref.shape, jnp.float32)
-        acc = jax.lax.fori_loop(0, num_splits, body, acc0)
+        # m_ref is the only life M ever has
+        acc = split_contract(left_ref[...], m_ref[...], idx1_ref, idx2_ref, num_splits)
         out_ref[...] = acc.astype(out_ref.dtype)
 
 
@@ -127,8 +120,7 @@ def fused_count_pallas(
         kernel,
         grid=(nrb, spb),
         in_specs=[
-            pl.BlockSpec((1, tile), lambda i, j: (i * spb + j, 0)),
-            pl.BlockSpec((1, tile), lambda i, j: (i * spb + j, 0)),
+            *slab_specs(tile, spb),
             pl.BlockSpec((c, b), lambda i, j: (0, 0)),
             pl.BlockSpec((row_tile, a), lambda i, j: (i, 0)),
             pl.BlockSpec((idx1_t.shape[0], s_pad), lambda i, j: (0, 0)),
@@ -137,8 +129,9 @@ def fused_count_pallas(
         out_specs=pl.BlockSpec((row_tile, s_pad), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((out_rows, s_pad), left.dtype),
         scratch_shapes=[pltpu.VMEM((row_tile, b), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(slab_dst, slab_cols, right, left, idx1_t, idx2_t)
+    )(slab_dst[:, None], slab_cols[:, None], right, left, idx1_t, idx2_t)
 
 
 @functools.partial(jax.jit, static_argnames=("row_tile",))

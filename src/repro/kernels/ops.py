@@ -6,7 +6,9 @@ Every op has three implementations selected by ``impl``:
   and the semantics oracle (it *is* ``ref.py`` modulo padding plumbing).
 * ``"pallas"`` — the Pallas kernel, compiled on TPU, ``interpret=True``
   elsewhere (so CPU tests execute the actual kernel body).
-* ``"auto"`` — pallas on TPU backends, xla otherwise.
+* ``"auto"`` — per call: pallas on TPU backends when the kernel's VMEM
+  need (computed from the operand shapes) fits ``VMEM_LIMIT_BYTES``, xla
+  otherwise.  An explicit ``"pallas"`` that cannot fit raises.
 
 Sparse ops consume a prebuilt :class:`SpmmPlan` (host-side preprocessing of
 the graph into padded edge lists / block patches) so that jitted code sees
@@ -23,7 +25,7 @@ Padding conventions (hardware-true even in interpret mode):
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,20 +34,30 @@ import numpy as np
 from repro.compat import pvary_like
 
 from . import ref
-from .color_combine import color_combine_pallas
+from .color_combine import color_combine_pallas, combine_vmem_bytes
 from .flash_attention import flash_attention_pallas
-from .fused_count import fused_count_pallas, fused_count_xla
-from .spmm_edgetile import spmm_block_pallas, spmm_edge_tile_pallas
+from .fused_count import fused_count_pallas, fused_count_xla, fused_vmem_bytes
+from .spmm_edgetile import (
+    VMEM_LIMIT_BYTES,
+    block_vmem_bytes,
+    edge_tile_vmem_bytes,
+    spmm_block_pallas,
+    spmm_edge_tile_pallas,
+)
 
 __all__ = [
     "on_tpu",
     "resolve_impl",
+    "spmm_impl",
+    "combine_impl",
+    "fused_impl",
     "pad_to",
     "SpmmPlan",
     "build_spmm_plan",
     "build_slab_layout",
     "build_bucket_tiles",
     "expected_patch_density",
+    "gather_scatter_add",
     "spmm",
     "spmm_compact",
     "spmm_slabs",
@@ -63,13 +75,47 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def resolve_impl(impl: str) -> str:
+def resolve_impl(impl: str, vmem_bytes: int = 0) -> str:
+    """The implementation one op call runs, from ``impl`` and the VMEM its
+    Pallas kernel would hold for these operand shapes."""
+    if vmem_bytes > VMEM_LIMIT_BYTES:
+        if impl == "pallas":
+            raise ValueError(
+                f"impl='pallas': this kernel call needs {vmem_bytes / 2**20:.1f} MiB "
+                f"of VMEM, over the {VMEM_LIMIT_BYTES >> 20} MiB limit; "
+                f"use impl='auto' or impl='xla'"
+            )
+        if impl == "auto":
+            return "xla"
     if impl == "auto":
         return "pallas" if on_tpu() else "xla"
     return impl
 
 
-_resolve = resolve_impl
+def spmm_impl(plan: "SpmmPlan", width: int, impl: str, table_rows: Optional[int] = None) -> str:
+    """Implementation of one neighbor sum over a ``[table_rows, width]``
+    source table (``table_rows`` defaults to the plan's ``n_pad``)."""
+    if plan.kind == "blocks":
+        need = block_vmem_bytes(plan.block_size, width)
+    else:
+        need = edge_tile_vmem_bytes(table_rows or plan.n_pad, width, plan.row_tile)
+    return resolve_impl(impl, need)
+
+
+def combine_impl(a: int, b: int, tables: "CombineTables", impl: str) -> str:
+    """Implementation of one combine of ``[n, a]`` by ``[n, b]`` tables."""
+    return resolve_impl(impl, combine_vmem_bytes(a, b, tables.idx1_t.shape[0]))
+
+
+def fused_impl(
+    a: int, b: int, tables: "CombineTables", impl: str, table_rows: int, row_tile: int = 128
+) -> str:
+    """Implementation of one fused count against a ``[table_rows, b]``
+    right table."""
+    need = fused_vmem_bytes(
+        table_rows, a, b, tables.idx1_t.shape[1], tables.idx1_t.shape[0], row_tile
+    )
+    return resolve_impl(impl, need)
 
 
 def pad_to(x: int, multiple: int) -> int:
@@ -121,6 +167,32 @@ class SpmmPlan:
     row_tile: int = 128
     #: measured edges per occupied 128x128 patch (set by kind='auto')
     patch_density: Optional[float] = None
+
+    @property
+    def layout_bytes(self) -> Dict[str, int]:
+        """Device bytes of each index layout this plan holds."""
+        out = {}
+        for name in ("rows", "cols", "slab_dst", "slab_cols", "patches"):
+            a = getattr(self, name)
+            if a is not None:
+                out[name] = int(a.size) * a.dtype.itemsize
+        return out
+
+
+# a pytree, so jitted counters take the (device-resident) layout as an
+# argument: a closed-over array would be embedded in the program as a
+# constant, which at deployment size is gigabytes of HLO
+jax.tree_util.register_dataclass(
+    SpmmPlan,
+    data_fields=[
+        "rows", "cols", "block_rows", "block_cols", "patches",
+        "written_mask", "slab_dst", "slab_cols",
+    ],
+    meta_fields=[
+        "kind", "n", "n_pad", "block_size", "slabs_per_block", "tile_size",
+        "row_tile", "patch_density",
+    ],
+)
 
 
 #: 'auto' picks the block-dense plan once occupied 128x128 patches average
@@ -339,19 +411,64 @@ def expected_patch_density(n: int, e_directed: int, block: int = 128) -> float:
     return float(e_directed) / max(occupied, 1.0)
 
 
+#: bound, in elements, on the gathered ``[edges, B]`` intermediate of the
+#: XLA neighbor sums; longer edge lists are summed in chunks.  Unchunked,
+#: XLA materializes every gathered row (15 GB for a 2^20-vertex Graph500
+#: graph at B = 128).
+XLA_GATHER_ELEMENTS = 1 << 26
+
+
+def gather_scatter_add(
+    table: jax.Array,
+    cols: jax.Array,
+    rows: jax.Array,
+    num_rows: int,
+    *,
+    indices_are_sorted: bool = False,
+) -> jax.Array:
+    """``out[rows[e]] += table[cols[e]]`` over ``num_rows`` output rows;
+    ``rows`` out of range are dropped.  The XLA neighbor sum, with the
+    gathered intermediate bounded by ``XLA_GATHER_ELEMENTS``."""
+    e, b = cols.shape[0], table.shape[1]
+    chunk = max(1, XLA_GATHER_ELEMENTS // b)
+    if e <= chunk:
+        return jax.ops.segment_sum(
+            jnp.take(table, cols, axis=0),
+            rows,
+            num_segments=num_rows,
+            indices_are_sorted=indices_are_sorted,
+        )
+    steps = -(-e // chunk)
+    pad = steps * chunk - e
+    cols = jnp.pad(cols, (0, pad)).reshape(steps, chunk)
+    rows = jnp.pad(rows, (0, pad), constant_values=num_rows).reshape(steps, chunk)
+
+    def step(acc, rc):
+        r, c = rc
+        acc = acc.at[r].add(
+            jnp.take(table, c, axis=0), mode="drop", indices_are_sorted=indices_are_sorted
+        )
+        return acc, None
+
+    acc0 = pvary_like(jnp.zeros((num_rows, b), table.dtype), table)
+    return jax.lax.scan(step, acc0, (rows, cols))[0]
+
+
 def spmm(plan: SpmmPlan, table: jax.Array, impl: str = "auto") -> jax.Array:
     """Neighbor sum ``M[v] = sum_{(v,u) in E} table[u]``.
 
     ``table``: [n_pad, B_pad]; returns [n_pad, B_pad].  Rows >= plan.n of the
     input must be zero; output rows >= plan.n are unspecified (engine masks).
     """
-    impl = _resolve(impl)
     n_pad, b = table.shape
     assert n_pad == plan.n_pad, (n_pad, plan.n_pad)
+    impl = spmm_impl(plan, b, impl)
     if plan.kind == "edges":
         if impl == "xla":
-            out = jax.ops.segment_sum(table[plan.cols], plan.rows, num_segments=plan.n_pad)
-            return out
+            # rows are dst-sorted, pads (the sentinel row n) last
+            return gather_scatter_add(
+                table, plan.cols, plan.rows, plan.n_pad, indices_are_sorted=True
+            )
         # edge-tiled kernel writes every output block (pad slabs contribute
         # zeros), so zero-degree rows come out correctly zeroed
         return spmm_edge_tile_pallas(
@@ -400,11 +517,12 @@ def spmm_compact(
     Requires an edge plan (``kind == 'edges'``).  Returns ``[n_pad, B]``;
     output rows >= plan.n are unspecified (engine masks).
     """
-    impl = _resolve(impl)
     assert plan.kind == "edges", "spmm_compact needs the edge-slab layout"
+    impl = spmm_impl(plan, table_c.shape[1], impl, table_rows=table_c.shape[0])
     if impl == "xla":
-        gathered = jnp.take(table_c, jnp.take(inv, plan.cols), axis=0)
-        return jax.ops.segment_sum(gathered, plan.rows, num_segments=plan.n_pad)
+        return gather_scatter_add(
+            table_c, jnp.take(inv, plan.cols), plan.rows, plan.n_pad, indices_are_sorted=True
+        )
     return spmm_edge_tile_pallas(
         plan.slab_dst,
         jnp.take(inv, plan.slab_cols),
@@ -439,19 +557,16 @@ def spmm_slabs(
     exchange, DESIGN.md §18); it is widened to float32 here, once, so both
     kernel paths keep their float32 contract.
     """
-    impl = _resolve(impl)
     if table.dtype != jnp.float32:
         table = table.astype(jnp.float32)
+    impl = resolve_impl(impl, edge_tile_vmem_bytes(table.shape[0], table.shape[1], row_tile))
     num_slabs, tile = slab_dst.shape
     nrb = out_rows // row_tile
     assert num_slabs == nrb * slabs_per_block, (num_slabs, nrb, slabs_per_block)
     if impl == "xla":
         blk = (jnp.arange(num_slabs, dtype=jnp.int32) // slabs_per_block) * row_tile
         dst_g = jnp.where(slab_dst < 0, out_rows, slab_dst + blk[:, None])
-        gathered = jnp.take(table, slab_cols.reshape(-1), axis=0)
-        return jax.ops.segment_sum(
-            gathered, dst_g.reshape(-1), num_segments=out_rows + 1
-        )[:out_rows]
+        return gather_scatter_add(table, slab_cols.reshape(-1), dst_g.reshape(-1), out_rows)
     return spmm_edge_tile_pallas(
         slab_dst,
         slab_cols,
@@ -522,7 +637,7 @@ def color_combine(
 
     Returns [n_pad, S_pad]; pad rows/cols are unspecified (engine masks).
     """
-    impl = _resolve(impl)
+    impl = combine_impl(left.shape[1], m.shape[1], tables, impl)
     if impl == "xla":
         n = left.shape[0]
         s, j = tables.idx1.shape
@@ -558,6 +673,11 @@ def color_combine(
         if out.shape[1] < s_out:
             out = jnp.pad(out, ((0, 0), (0, s_out - out.shape[1])))
         return out
+    n = left.shape[0]
+    rows = pad_to(n, 128)  # the kernel's row tile
+    if rows != n:
+        left = jnp.pad(left, ((0, rows - n), (0, 0)))
+        m = jnp.pad(m, ((0, rows - n), (0, 0)))
     return color_combine_pallas(
         left,
         m,
@@ -565,7 +685,7 @@ def color_combine(
         tables.idx2_t,
         num_splits=tables.j,
         interpret=not on_tpu(),
-    )
+    )[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -587,11 +707,11 @@ def fused_count(
     falls back to the two-step spmm + combine path.  Returns
     ``[n_pad, S_pad]``; pad rows/cols are unspecified (engine masks).
     """
-    impl = _resolve(impl)
     if plan.slab_dst is None:
         m = spmm(plan, right, impl=impl)
         mask = (jnp.arange(plan.n_pad) < plan.n).astype(m.dtype)[:, None]
         return color_combine(left, m * mask, tables, impl=impl)
+    impl = fused_impl(left.shape[1], right.shape[1], tables, impl, plan.n_pad, plan.row_tile)
     if impl == "xla":
         out = fused_count_xla(
             plan.slab_dst,
@@ -632,8 +752,10 @@ def fused_count_compact(
     :func:`spmm_compact` — the fused kernel's resident source table shrinks
     to ``cap`` rows and ``M`` still never materializes.  Requires the
     edge-slab layout.  Returns ``[n_pad, S_pad]`` (engine masks pads)."""
-    impl = _resolve(impl)
     assert plan.slab_dst is not None, "fused_count_compact needs edge slabs"
+    impl = fused_impl(
+        left.shape[1], right_c.shape[1], tables, impl, right_c.shape[0], plan.row_tile
+    )
     cols_c = jnp.take(inv, plan.slab_cols)
     if impl == "xla":
         out = fused_count_xla(
@@ -685,9 +807,9 @@ def fused_count_slabs(
     ``right`` may arrive at narrow wire width (the compressed exchange,
     DESIGN.md §18); it is widened to float32 here, once, before dispatch.
     """
-    impl = _resolve(impl)
     if right.dtype != jnp.float32:
         right = right.astype(jnp.float32)
+    impl = fused_impl(left.shape[1], right.shape[1], tables, impl, right.shape[0], row_tile)
     if impl == "xla":
         out = fused_count_xla(
             slab_dst,
@@ -731,7 +853,7 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
 ) -> jax.Array:
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     if impl == "xla":
         return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     return flash_attention_pallas(

@@ -6,21 +6,20 @@ embodying the paper's *neighbor-list partitioning* (§3.3) — bounded,
 uniform-size tasks independent of degree skew:
 
 ``spmm_edge_tile_pallas``
-    Edge-tiled gather SpMM.  The directed edge list is partitioned into
-    *slabs* of exactly ``tile_size`` edges (the paper's bounded task size
-    ``s``), grouped under the 128-row output block their destinations fall
-    in; the grid is ``(row_blocks, slabs_per_block)`` with the slab axis
-    innermost so output-block revisits are consecutive and a ``j == 0``
-    first-visit check re-zeroes the resident accumulator.  Each grid step
-    gathers the slab's ``tile_size`` source rows from the VMEM-resident
-    table and scatters them into the output block with one
-    ``[rows, tile] x [tile, B]`` one-hot MXU matmul — a max-degree
-    "supernode" row simply owns many slabs, every task is the same two
-    dense ops.  Padded slab slots carry ``dst = -1`` (all-zero one-hot row)
-    and the zero sentinel source row, so they are arithmetic no-ops.
-    The whole count table is held resident in VMEM (constant index_map), so
-    this kernel is for tables up to a few MB; larger graphs take
-    ``spmm_block_pallas`` or the XLA scatter path.
+    Edge-tiled SpMM.  The directed edge list is partitioned into *slabs* of
+    exactly ``tile_size`` edges (the paper's bounded task size ``s``),
+    grouped under the 128-row output block their destinations fall in; the
+    grid is ``(row_blocks, slabs_per_block)`` with the slab axis innermost
+    so output-block revisits are consecutive and a ``j == 0`` first-visit
+    check re-zeroes the resident accumulator.  Each grid step brings the
+    slab's destination and source indices into SMEM and adds source row
+    ``C[col]`` into output row ``dst`` one edge at a time (dynamic
+    one-row loads and stores, which Mosaic lowers; a vector gather by an
+    index vector it does not).  A max-degree "supernode" row simply owns
+    many slabs.  Padded slab slots carry ``dst = -1`` and the zero sentinel
+    source row, so they add zeros.  The whole count table is held resident
+    in VMEM (constant index_map); ``edge_tile_vmem_bytes`` says how much,
+    and ``ops`` routes tables that do not fit to the XLA path.
 
 ``spmm_block_pallas``
     Block-dense SpMM.  The adjacency is tiled into dense 128x128 0/1
@@ -44,12 +43,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["spmm_block_pallas", "spmm_edge_tile_pallas"]
+__all__ = [
+    "VMEM_LIMIT_BYTES",
+    "block_vmem_bytes",
+    "edge_tile_vmem_bytes",
+    "spmm_block_pallas",
+    "spmm_edge_tile_pallas",
+]
+
+#: scoped VMEM the table-resident kernels ask Mosaic for.  A v5e TensorCore
+#: has 128 MiB of VMEM; the compiler's default scoped limit is 16 MiB.
+VMEM_LIMIT_BYTES = 64 << 20
 
 
 # ---------------------------------------------------------------------------
 # Block-dense SpMM (MXU path)
 # ---------------------------------------------------------------------------
+
+
+def block_vmem_bytes(block_size: int, width: int) -> int:
+    """VMEM of one block-kernel step: the double-buffered 0/1 patch, source
+    block and output block (float32)."""
+    return 2 * 4 * block_size * (block_size + 2 * width)
 
 
 def _block_kernel(block_rows_ref, block_cols_ref, patch_ref, table_ref, out_ref):
@@ -64,8 +79,12 @@ def _block_kernel(block_rows_ref, block_cols_ref, patch_ref, table_ref, out_ref)
 
     patch = patch_ref[0]  # [VB, KB]
     ctab = table_ref[...]  # [KB, B]
+    # HIGHEST: full float32 passes on the MXU, so integer counts stay exact
     out_ref[...] += jnp.dot(
-        patch, ctab.astype(jnp.float32), preferred_element_type=jnp.float32
+        patch,
+        ctab.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     ).astype(out_ref.dtype)
 
 
@@ -96,14 +115,49 @@ def spmm_block_pallas(
         _block_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(((num_row_blocks + 1) * vb, b), table.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(block_rows, block_cols, patches, table)
     return out
 
 
 # ---------------------------------------------------------------------------
-# Edge-tiled gather SpMM (general-sparsity path, tile_size edges per step)
+# Edge-tiled SpMM (general-sparsity path, tile_size edges per step)
 # ---------------------------------------------------------------------------
+
+
+def slab_specs(tile: int, spb: int):
+    """SMEM block specs for one slab of the ``[num_slabs, 1, tile]`` dst/col
+    index arrays at grid step ``(i, j)``: slab ``i * spb + j``.  The unit
+    middle axis makes the block's last two dims equal the array's, which
+    the TPU tiling rule accepts for any ``tile``."""
+    spec = pl.BlockSpec(
+        (None, 1, tile), lambda i, j: (i * spb + j, 0, 0), memory_space=pltpu.SMEM
+    )
+    return [spec, spec]
+
+
+def scatter_slab(dst_ref, col_ref, table_ref, acc_ref):
+    """``acc[dst[e]] += table[col[e]]`` for the slab's ``tile`` edges.
+
+    Pad slots (``dst = -1``) are clamped onto row 0 and read the all-zero
+    sentinel source row, so they add exact zeros."""
+
+    def body(e, carry):
+        d = jnp.maximum(dst_ref[0, e], 0)
+        c = col_ref[0, e]
+        row = table_ref[pl.ds(c, 1), :].astype(acc_ref.dtype)
+        acc_ref[pl.ds(d, 1), :] += row
+        return carry
+
+    jax.lax.fori_loop(0, dst_ref.shape[1], body, 0)
+
+
+def edge_tile_vmem_bytes(table_rows: int, width: int, row_tile: int = 128) -> int:
+    """VMEM the edge-tile kernel holds: the resident ``[table_rows, width]``
+    source table and the ``[row_tile, width]`` output block, each double
+    buffered by the pipeline (float32)."""
+    return 2 * 4 * (table_rows + row_tile) * width
 
 
 def _edge_tile_kernel(dst_ref, col_ref, table_ref, out_ref):
@@ -113,22 +167,7 @@ def _edge_tile_kernel(dst_ref, col_ref, table_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    dst = dst_ref[0]  # [tile_size] int32 local dst row; -1 = pad slot
-    cols = col_ref[0]  # [tile_size] int32 global src row; sentinel = zero row
-    tab = table_ref[...]  # [n_pad, B] resident across the whole grid
-    gathered = jnp.take(tab, cols, axis=0).astype(jnp.float32)  # [tile, B]
-    row_tile = out_ref.shape[0]
-    onehot = (
-        dst[:, None]
-        == jax.lax.broadcasted_iota(jnp.int32, (dst.shape[0], row_tile), 1)
-    ).astype(jnp.float32)  # [tile, rows]; pad slots are all-zero rows
-    # scatter-accumulate as one MXU matmul: out[r] += sum_i [dst_i == r] * C[col_i]
-    out_ref[...] += jax.lax.dot_general(
-        onehot,
-        gathered,
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(out_ref.dtype)
+    scatter_slab(dst_ref, col_ref, table_ref, out_ref)
 
 
 @functools.partial(
@@ -155,16 +194,15 @@ def spmm_edge_tile_pallas(
     spb = slabs_per_block
     num_slabs, tile = slab_dst.shape
     assert num_slabs == nrb * spb, (num_slabs, nrb, spb)
-    grid = (nrb, spb)
     return pl.pallas_call(
         _edge_tile_kernel,
-        grid=grid,
+        grid=(nrb, spb),
         in_specs=[
-            pl.BlockSpec((1, tile), lambda i, j: (i * spb + j, 0)),
-            pl.BlockSpec((1, tile), lambda i, j: (i * spb + j, 0)),
+            *slab_specs(tile, spb),
             pl.BlockSpec((c, b), lambda i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((row_tile, b), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((out_rows, b), table.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(slab_dst, slab_cols, table)
+    )(slab_dst[:, None], slab_cols[:, None], table)

@@ -31,18 +31,24 @@ import jax
 from repro.api import Counter
 from repro.configs import COUNTING_CONFIGS
 from repro.core import load_edge_file, load_npz
+from repro.core.count_engine import node_kernels
 from repro.core.estimator import num_groups_for
 from repro.core.templates import TEMPLATES
+from repro.launch.compile_cache import use_compile_cache
 
 
 def _plan_report(plan):
-    """Surface the density signals the plan's adaptive choices used: the
-    spmm auto patch density and the per-node table densities / capacities
-    of active-frontier compaction (§15)."""
+    """Surface the signals the plan's adaptive choices used: the spmm auto
+    patch density, the kernel each node's ops run (chosen from the node's
+    table shapes against the kernels' VMEM limit), and the per-node table
+    densities / capacities of active-frontier compaction (§15)."""
     spmm = getattr(plan, "spmm_plan", None)
     if spmm is not None and spmm.patch_density is not None:
         print(f"spmm auto: {spmm.patch_density:.1f} edges/patch "
               f"-> kind={spmm.kind}")
+    if spmm is not None:
+        for i, choice in sorted(node_kernels(plan).items()):
+            print(f"  node {i}: {choice}")
     spec = getattr(plan, "compaction", None)
     if spec is None:
         return
@@ -179,6 +185,10 @@ def main():
     args = ap.parse_args()
     if args.batch < 1:
         ap.error(f"--batch must be >= 1 (got {args.batch})")
+    cache_dir = use_compile_cache()
+    dev = jax.devices()[0]
+    print(f"devices: {jax.device_count()} x {dev.platform} ({dev.device_kind}); "
+          f"compile cache {cache_dir}")
     ckpt_dir = args.resume or args.checkpoint_dir
     ckpt_every = args.checkpoint_every or (args.batch if ckpt_dir else 0)
     robust_kw = dict(

@@ -22,6 +22,7 @@ import argparse
 import json
 
 from repro.configs import SERVICE_WORKLOADS
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve import CountingService, ServiceConfig
 
 
@@ -105,6 +106,7 @@ def main():
                     help="under overload, shed the oldest queued request "
                          "instead of rejecting the new submit")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg_kw = {}
     if args.timeout_s is not None:

@@ -37,8 +37,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
-
 from .layers import Initializer
 
 __all__ = ["moe_init", "moe_block", "moe_block_manual"]
@@ -156,7 +154,7 @@ def moe_block_manual(
     dtype=jnp.bfloat16,
 ) -> Tuple[jax.Array, jax.Array]:
     ep = cfg.moe_sharding == "ep"
-    pm = axis_size(model_axis)
+    pm = jax.lax.axis_size(model_axis)
     m = jax.lax.axis_index(model_axis)
     b, l, d = x.shape
     t = b * l

@@ -24,8 +24,8 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
-from repro.compat import shard_map
 from .attention import attn_init, attention_block, init_kv_cache
 from .layers import Initializer, mlp_apply, mlp_init, rmsnorm
 from .moe import moe_block, moe_init

@@ -13,13 +13,12 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
+from jax import shard_map  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# version-compat shims: jax.sharding.AxisType / jax.shard_map are not present
-# on every supported JAX release (see repro.compat).
-from repro.compat import make_mesh, shard_map  # noqa: E402
+from repro.compat import make_mesh  # noqa: E402
 
 FAILURES = []
 

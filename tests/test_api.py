@@ -199,3 +199,46 @@ class TestShardColoring:
             hi = min((p + 1) * plan.shard_size, plan.n)
             want[p, : hi - lo] = coloring[lo:hi]
         np.testing.assert_array_equal(got, want)
+
+
+class TestPlacement:
+    def test_distributed_plan_arrays_are_placed_once(self):
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        g = erdos_renyi(57, 4.0, seed=3)
+        c = Counter.from_graph(g, path_tree(3), backend="distributed", num_shards=1)
+        for arr in c.plan.device_arrays:
+            assert isinstance(arr.sharding, NamedSharding)
+            assert arr.sharding.spec == PartitionSpec("data")
+        # a mode switch reuses the placed plan instead of copying it
+        assert c.with_options(mode="ring").plan is c.plan
+
+
+class TestCompileCache:
+    @pytest.mark.parametrize("env", [None, "elsewhere/jax-cache"])
+    def test_cache_dir(self, monkeypatch, env):
+        import pathlib
+
+        from repro.launch.compile_cache import REPO_CACHE_DIR, use_compile_cache
+
+        repo = pathlib.Path(__file__).resolve().parents[1]
+        assert REPO_CACHE_DIR == repo / ".jax_cache"
+        if env is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+        was = jax.config.jax_compilation_cache_dir
+        was_min = jax.config.jax_persistent_cache_min_compile_time_secs
+        try:
+            got = use_compile_cache()
+            now = jax.config.jax_compilation_cache_dir
+            # every program is cached, however quick its compile
+            assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
+            jax.config.update("jax_persistent_cache_min_compile_time_secs", was_min)
+        if env is None:
+            assert got == now == str(REPO_CACHE_DIR)
+        else:
+            # JAX reads the variable itself: nothing is set in code
+            assert got == env and now == was

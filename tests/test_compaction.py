@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.api import Counter
 from repro.core import rmat
+from repro.core.graphs import RMAT_SKEW
 from repro.core.brute_force import count_colorful_maps
 from repro.core.count_engine import (
     build_counting_plan,
@@ -338,7 +339,7 @@ class TestPropertyParity:
 
         @given(
             st.integers(100, 500),
-            st.integers(3, 9),
+            st.sampled_from(sorted(RMAT_SKEW)),
             st.sampled_from(["p4", "sp21", "u5-2"]),
             st.floats(0.05, 2.0),
             st.integers(0, 10_000),

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.api import Counter
-from repro.comm.adaptive import V5E_ICI, calibrate, choose_mode_full
+from repro.comm.adaptive import V5E_ICI, assumed_model, calibrate, choose_mode_full
 from repro.comm.compress import (
     WIRE_DTYPES,
     WIRE_ESCALATION,
@@ -152,6 +152,14 @@ class TestRouter:
     def test_calibrate_single_device_returns_base(self):
         mesh = make_mesh((1,), ("data",))
         assert calibrate(mesh, "data") is V5E_ICI
+
+    @pytest.mark.parametrize("kind", ["TPU v5 lite", "cpu"])
+    def test_assumed_model_by_device_kind(self, kind):
+        assert assumed_model(kind) is V5E_ICI
+
+    def test_unknown_device_kind_raises(self):
+        with pytest.raises(ValueError, match="no assumed link model"):
+            assumed_model("TPU v9 imaginary")
 
 
 class TestSampledDensity:

@@ -118,6 +118,55 @@ class TestSpmmKernels:
         b = ops.spmm(eplan, table, impl="xla")
         np.testing.assert_allclose(a[: g.n], b[: g.n], rtol=1e-6)
 
+    @pytest.mark.parametrize("chunk_elements", [128 * 7, 128 * 64])
+    def test_chunked_gather_scatter_matches_segment_sum(self, monkeypatch, chunk_elements):
+        # chunks of 7 and 64 edges: several scan steps, with a padded tail
+        monkeypatch.setattr(ops, "XLA_GATHER_ELEMENTS", chunk_elements)
+        g = rmat(300, 2000, skew=8, seed=6)
+        plan = ops.build_spmm_plan(*edge_list(g), g.n, kind="edges")
+        table = _random_table(np.random.default_rng(6), plan.n_pad, 128, g.n)
+        want = jax.ops.segment_sum(table[plan.cols], plan.rows, num_segments=plan.n_pad)
+        got = ops.gather_scatter_add(table, plan.cols, plan.rows, plan.n_pad)
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+
+
+class TestRouting:
+    """``impl='auto'`` picks per op from the kernel's VMEM need."""
+
+    @pytest.mark.parametrize(
+        "tpu,need,want",
+        [(True, 0, "pallas"), (True, ops.VMEM_LIMIT_BYTES + 1, "xla"),
+         (False, 0, "xla"), (False, ops.VMEM_LIMIT_BYTES + 1, "xla")],
+    )
+    def test_auto_resolves_from_vmem_need(self, monkeypatch, tpu, need, want):
+        monkeypatch.setattr(ops, "on_tpu", lambda: tpu)
+        assert ops.resolve_impl("auto", need) == want
+        assert ops.resolve_impl("xla", need) == "xla"
+
+    def test_explicit_pallas_over_limit_raises(self):
+        assert ops.resolve_impl("pallas", ops.VMEM_LIMIT_BYTES) == "pallas"
+        with pytest.raises(ValueError, match="impl='pallas'.*VMEM"):
+            ops.resolve_impl("pallas", ops.VMEM_LIMIT_BYTES + 1)
+
+    @pytest.mark.parametrize("fuse", [False, True])
+    def test_node_kernels_follow_table_size(self, monkeypatch, fuse):
+        from repro.core.count_engine import build_counting_plan, node_kernels
+        from repro.core.templates import template
+
+        monkeypatch.setattr(ops, "on_tpu", lambda: True)
+        g = rmat(2000, 6000, skew=3, seed=7)
+        plan = build_counting_plan(g, template("u5-2"), impl="auto", fuse=fuse)
+        small = node_kernels(plan)
+        assert small and all("xla" not in c for c in small.values())
+        # a limit below the edge/fused kernels' resident table, above the
+        # row-tiled combine's step: only the table-resident ops move
+        limit = ops.edge_tile_vmem_bytes(plan.n_pad, 128) // 2
+        monkeypatch.setattr(ops, "VMEM_LIMIT_BYTES", limit)
+        big = node_kernels(plan)
+        assert big.keys() == small.keys()
+        for choice in big.values():
+            assert choice == ("fused=xla" if fuse else "spmm=xla combine=pallas")
+
 
 class TestColorCombine:
     @pytest.mark.parametrize("k,t1,t2", [(5, 2, 2), (7, 3, 2), (10, 3, 3), (12, 4, 3)])
@@ -158,7 +207,7 @@ class TestColorCombine:
 
 def _iter_eqns(jaxpr):
     """All equations of a jaxpr, recursing into sub-jaxprs (scan/cond/...)."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def subs(v):
         if isinstance(v, Jaxpr):

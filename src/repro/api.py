@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.count_engine import (
     build_counting_plan,
     build_edge_plan,
@@ -796,7 +797,8 @@ class Counter:
         if key is None:
             key = jax.random.key(0)
         while True:
-            key, sub = jax.random.split(key)
+            with obs.span("stream.next_key"):
+                key, sub = jax.random.split(key)
             yield self.sample_fn(sub, batch)
 
     # ---------------------------------------------------------------- serving

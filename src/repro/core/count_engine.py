@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels import ops
 from repro.testing import faults
 from .frontier import (
@@ -551,14 +552,27 @@ def _checked_fallback(compact_fn, make_dense):
 def _jit_counter(plan, f):
     """``jax.jit(f)`` over ``key`` with the plan's edge layout passed as an
     argument (never a closed-over constant, see :class:`ops.SpmmPlan`).
-    The returned callable also exposes ``lower(key)`` for memory analysis."""
-    jf = jax.jit(lambda sp, key: f(dataclasses.replace(plan, spmm_plan=sp), key))
+    The returned callable also exposes ``lower(key)`` for memory analysis.
+    The jitted step is named ``count_batch``: its module is
+    ``jit_count_batch`` in the compiler's output and the device trace."""
+
+    def count_batch(sp, key: jax.Array):
+        return f(dataclasses.replace(plan, spmm_plan=sp), key)
+
+    jf = jax.jit(count_batch)
 
     def call(key: jax.Array):
         return jf(plan.spmm_plan, key)
 
     call.lower = lambda key: jf.lower(plan.spmm_plan, key)
     return call
+
+
+def _draw_colorings(key: jax.Array, shape, k: int) -> jax.Array:
+    """A step's colorings, ``randint(key, shape, 0, k)``, under the device
+    scope ``coloring``."""
+    with jax.named_scope("coloring"):
+        return jax.random.randint(key, shape, 0, k, dtype=jnp.int32)
 
 
 def count_fn(plan: CountingPlan, batch: Optional[int] = None):
@@ -583,14 +597,14 @@ def count_fn(plan: CountingPlan, batch: Optional[int] = None):
     if batch is None:
 
         def f(p, key: jax.Array):
-            coloring = jax.random.randint(key, (p.n_pad,), 0, p.k, dtype=jnp.int32)
+            coloring = _draw_colorings(key, (p.n_pad,), p.k)
             maps, ok = count1(p, coloring)
             return (maps, maps * p.scale) if ok is None else (maps, maps * p.scale, ok)
 
     else:
 
         def f(p, key: jax.Array):
-            colorings = jax.random.randint(key, (batch, p.n_pad), 0, p.k, dtype=jnp.int32)
+            colorings = _draw_colorings(key, (batch, p.n_pad), p.k)
             maps, ok = jax.vmap(lambda c: count1(p, c))(colorings)
             return (maps, maps * p.scale) if not compact else (maps, maps * p.scale, ok)
 
@@ -615,14 +629,14 @@ def count_fn_many(plan: MultiCountingPlan, batch: Optional[int] = None):
     if batch is None:
 
         def f(p, key: jax.Array):
-            coloring = jax.random.randint(key, (p.n_pad,), 0, p.k, dtype=jnp.int32)
+            coloring = _draw_colorings(key, (p.n_pad,), p.k)
             maps, ok = count1(p, coloring)
             return (maps, maps * scales) if ok is None else (maps, maps * scales, ok)
 
     else:
 
         def f(p, key: jax.Array):
-            colorings = jax.random.randint(key, (batch, p.n_pad), 0, p.k, dtype=jnp.int32)
+            colorings = _draw_colorings(key, (batch, p.n_pad), p.k)
             maps, ok = jax.vmap(lambda c: count1(p, c))(colorings)
             return (maps, maps * scales[None, :]) if not compact else (
                 maps, maps * scales[None, :], ok
@@ -641,10 +655,13 @@ def _cached_sampler(make_fn):
 
     def sample(key: jax.Array, batch: int) -> np.ndarray:
         f = cache.get(batch)
-        if f is None:
+        first = f is None
+        if first:
             f = cache[batch] = make_fn(batch)
-        _, est = f(key)
-        return np.asarray(est, np.float64)
+        with obs.span("sample.dispatch", batch=batch, first=first):
+            _, est = f(key)
+        with obs.span("sample.wait"):  # the device's finish and the copy to the host
+            return np.asarray(est, np.float64)
 
     return sample
 

@@ -24,6 +24,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from repro import obs
+
 __all__ = [
     "Graph",
     "GraphFormatError",
@@ -96,17 +98,18 @@ def from_edges(n: int, edges: np.ndarray, name: str = "") -> Graph:
 
     Self loops and duplicate edges are removed.
     """
-    edges = np.asarray(edges, dtype=np.int64).reshape(-1, 2)
-    edges = edges[edges[:, 0] != edges[:, 1]]
-    lo = np.minimum(edges[:, 0], edges[:, 1])
-    hi = np.maximum(edges[:, 0], edges[:, 1])
-    # one int64 key per edge orders by (row, col); sorting keys is several
-    # times faster than a lexsort of pairs at Graph500 scale
-    key = np.unique(lo * n + hi)
-    both = np.sort(np.concatenate([key, (key % n) * n + key // n]))
-    indptr = np.zeros(n + 1, np.int64)
-    np.cumsum(np.bincount(both // n, minlength=n), out=indptr[1:])
-    indices = (both % n).astype(np.int32)
+    with obs.span("plan.from_edges"):
+        edges = np.asarray(edges, dtype=np.int64).reshape(-1, 2)
+        edges = edges[edges[:, 0] != edges[:, 1]]
+        lo = np.minimum(edges[:, 0], edges[:, 1])
+        hi = np.maximum(edges[:, 0], edges[:, 1])
+        # one int64 key per edge orders by (row, col); sorting keys is several
+        # times faster than a lexsort of pairs at Graph500 scale
+        key = np.unique(lo * n + hi)
+        both = np.sort(np.concatenate([key, (key % n) * n + key // n]))
+        indptr = np.zeros(n + 1, np.int64)
+        np.cumsum(np.bincount(both // n, minlength=n), out=indptr[1:])
+        indices = (both % n).astype(np.int32)
     return Graph(n, indptr, indices, name)
 
 

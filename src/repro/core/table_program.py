@@ -36,11 +36,13 @@ cannot drift.
 
 from __future__ import annotations
 
+import math
 from typing import Callable, Dict, List, Mapping, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import ops
 from .frontier import (
     CompactionSpec,
@@ -115,7 +117,22 @@ def build_node_tables(
     the pinned-apex axis, so the recorded width is the *stored* column
     count — ``x_dim`` per-x blocks of the lane-padded block width ``W``.
     Collapsed/joined tables live on the ``x`` axis itself (one block wide).
+
+    While :mod:`repro.obs` records, counts the columns of the tree tables
+    that the program's neighbor sums read: ``neighbor_sum.columns_true``
+    (``C(k, t)`` per right child) and ``neighbor_sum.columns_stored`` (its
+    padded width).
     """
+    with obs.span("plan.node_tables"):
+        combine, widths = _node_tables(program, k, lane, x_dim)
+    for nd in program.nodes:
+        if nd.kind == "combine":
+            obs.count("neighbor_sum.columns_true", math.comb(k, program.nodes[nd.right].size))
+            obs.count("neighbor_sum.columns_stored", widths[nd.right])
+    return combine, widths
+
+
+def _node_tables(program, k, lane, x_dim):
     combine: Dict[int, ops.CombineTables] = {}
     widths: Dict[int, int] = {}
     for i, nd in enumerate(program.nodes):
@@ -141,7 +158,8 @@ def build_node_tables(
 
 def leaf_table(coloring: jax.Array, k_pad: int, row_mask: jax.Array) -> jax.Array:
     """Leaf tables: one-hot of the coloring, pad rows zeroed."""
-    return jax.nn.one_hot(coloring, k_pad, dtype=jnp.float32) * row_mask
+    with jax.named_scope("leaf"):
+        return jax.nn.one_hot(coloring, k_pad, dtype=jnp.float32) * row_mask
 
 
 def run_table_program(
@@ -200,37 +218,40 @@ def run_table_program(
         kind = nd.kind
         if kind.startswith("bag_") and bag is None:
             raise ValueError("program has bag nodes but no BagFns strategy")
-        if kind == "leaf":
-            out = leaf  # leaves are dense: every vertex has a color
-        elif kind == "bag_leaf":
-            out = bag.leaf_fn(i, nd)
-        elif kind == "bag_collapse":
-            # strategy output is final (pad columns of the child are already
-            # zero and survive the sum as zero); rows are the x axis
-            out = bag.collapse_fn(i, tables[nd.left])
-        elif kind == "bag_join":
-            tbl = combine[i]
-            raw = bag.join_fn(i, tbl, tables[nd.left], tables[nd.right])
-            col_mask = (jnp.arange(raw.shape[1]) < tbl.s).astype(jnp.float32)[None, :]
-            out = raw * col_mask
-        else:  # "combine" / "bag_combine": the neighbor-sum contraction
-            tbl = combine[i]
-            raw = node_fn(
-                i,
-                tbl,
-                tables[nd.left],
-                tables[nd.right],
-                frontiers.get(nd.left),
-                frontiers.get(nd.right),
-            )
-            if kind == "bag_combine":
-                # one true-width block per x: mask repeats every s_pad cols
-                col_mask = (jnp.arange(raw.shape[1]) % tbl.s_pad < tbl.s).astype(
-                    jnp.float32
-                )[None, :]
-            else:
-                col_mask = (jnp.arange(raw.shape[1]) < tbl.s).astype(jnp.float32)[None, :]
-            out = raw * row_mask * col_mask
+        with jax.named_scope(f"node{i}"):
+            if kind == "leaf":
+                out = leaf  # leaves are dense: every vertex has a color
+            elif kind == "bag_leaf":
+                out = bag.leaf_fn(i, nd)
+            elif kind == "bag_collapse":
+                # strategy output is final (pad columns of the child are already
+                # zero and survive the sum as zero); rows are the x axis
+                out = bag.collapse_fn(i, tables[nd.left])
+            elif kind == "bag_join":
+                tbl = combine[i]
+                raw = bag.join_fn(i, tbl, tables[nd.left], tables[nd.right])
+                with jax.named_scope("mask"):
+                    col_mask = (jnp.arange(raw.shape[1]) < tbl.s).astype(jnp.float32)[None, :]
+                    out = raw * col_mask
+            else:  # "combine" / "bag_combine": the neighbor-sum contraction
+                tbl = combine[i]
+                raw = node_fn(
+                    i,
+                    tbl,
+                    tables[nd.left],
+                    tables[nd.right],
+                    frontiers.get(nd.left),
+                    frontiers.get(nd.right),
+                )
+                with jax.named_scope("mask"):
+                    if kind == "bag_combine":
+                        # one true-width block per x: mask repeats every s_pad cols
+                        col_mask = (jnp.arange(raw.shape[1]) % tbl.s_pad < tbl.s).astype(
+                            jnp.float32
+                        )[None, :]
+                    else:
+                        col_mask = (jnp.arange(raw.shape[1]) < tbl.s).astype(jnp.float32)[None, :]
+                    out = raw * row_mask * col_mask
         # the children just had one read each consumed; free at zero
         # (left may equal right for symmetric splits — counted twice)
         for c in nd.children[::-1]:
@@ -239,7 +260,8 @@ def run_table_program(
                 tables.pop(c, None)
                 frontiers.pop(c, None)
         if i in want:
-            delivered[i] = root_fn(out) if root_fn is not None else out
+            with jax.named_scope("root"):
+                delivered[i] = root_fn(out) if root_fn is not None else out
             reads[i] -= want[i]
         if reads[i] > 0:
             tables[i] = out
@@ -300,33 +322,40 @@ def local_node_fn(
         return table_c, inv
 
     def neighbor_sum(c_right, f_right):
-        right_c, inv = compact_right(c_right, f_right)
-        if right_c is not None:
-            return ops.spmm_compact(spmm_plan, right_c, inv, impl=impl)
-        return ops.spmm(spmm_plan, c_right, impl=impl)
+        with jax.named_scope("neighbor_sum"):
+            right_c, inv = compact_right(c_right, f_right)
+            if right_c is not None:
+                return ops.spmm_compact(spmm_plan, right_c, inv, impl=impl)
+            return ops.spmm(spmm_plan, c_right, impl=impl)
 
     def node_fn(i, tbl, c_left, c_right, f_left, f_right):
         cap = compaction.combine_caps.get(i) if compaction is not None else None
         if cap is not None:
             m = neighbor_sum(c_right, f_right)
-            return compact_combine(
-                c_left,
-                m,
-                tbl,
-                cap,
-                sentinel_row,
-                impl,
-                flags,
-                left_mask=f_left.mask if f_left is not None else None,
-            )
+            with jax.named_scope("combine"):
+                return compact_combine(
+                    c_left,
+                    m,
+                    tbl,
+                    cap,
+                    sentinel_row,
+                    impl,
+                    flags,
+                    left_mask=f_left.mask if f_left is not None else None,
+                )
         if fuse:
-            right_c, inv = compact_right(c_right, f_right)
-            if right_c is not None:
-                return ops.fused_count_compact(spmm_plan, c_left, right_c, inv, tbl, impl=impl)
-            return ops.fused_count(spmm_plan, c_left, c_right, tbl, impl=impl)
+            with jax.named_scope("fused"):
+                right_c, inv = compact_right(c_right, f_right)
+                if right_c is not None:
+                    return ops.fused_count_compact(
+                        spmm_plan, c_left, right_c, inv, tbl, impl=impl
+                    )
+                return ops.fused_count(spmm_plan, c_left, c_right, tbl, impl=impl)
         m = neighbor_sum(c_right, f_right)
-        # mask pad rows of the neighbor sum before the combine
-        m = m * row_mask
-        return ops.color_combine(c_left, m, tbl, impl=impl)
+        with jax.named_scope("neighbor_sum"):
+            # mask pad rows of the neighbor sum before the combine
+            m = m * row_mask
+        with jax.named_scope("combine"):
+            return ops.color_combine(c_left, m, tbl, impl=impl)
 
     return node_fn

@@ -112,4 +112,5 @@ def color_combine_pallas(
         out_shape=jax.ShapeDtypeStruct((n, s), left.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="color_combine",
     )(idx1_t, idx2_t, left, m)

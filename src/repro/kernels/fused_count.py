@@ -131,6 +131,7 @@ def fused_count_pallas(
         scratch_shapes=[pltpu.VMEM((row_tile, b), jnp.float32)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="fused_count",
     )(slab_dst[:, None], slab_cols[:, None], right, left, idx1_t, idx2_t)
 
 

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.compat import pvary_like
 
 from . import ref
@@ -347,9 +348,10 @@ def build_spmm_plan(
         c[:e] = cols
         written = np.zeros(n_pad, bool)
         written[r] = True
-        slab_dst, slab_cols, spb = _build_slabs(
-            np.asarray(rows), np.asarray(cols), n, n_pad, tile_size, row_tile
-        )
+        with obs.span("plan.slab_layout"):
+            slab_dst, slab_cols, spb = _build_slabs(
+                np.asarray(rows), np.asarray(cols), n, n_pad, tile_size, row_tile
+            )
         return SpmmPlan(
             "edges",
             n,

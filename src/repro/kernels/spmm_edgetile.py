@@ -117,6 +117,7 @@ def spmm_block_pallas(
         out_shape=jax.ShapeDtypeStruct(((num_row_blocks + 1) * vb, b), table.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="spmm_block",
     )(block_rows, block_cols, patches, table)
     return out
 
@@ -205,4 +206,5 @@ def spmm_edge_tile_pallas(
         out_shape=jax.ShapeDtypeStruct((out_rows, b), table.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="spmm_edge_tile",
     )(slab_dst[:, None], slab_cols[:, None], table)

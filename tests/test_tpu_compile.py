@@ -17,6 +17,7 @@ compiler.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -73,10 +74,14 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
-def _compile(fn, *shapes):
+def _compile(name, fn, *shapes):
     compiled = jax.jit(fn).lower(*shapes).compile()
-    # a Mosaic kernel, not the interpreter's XLA loop
-    assert "tpu_custom_call" in compiled.as_text()
+    # a Mosaic kernel, not the interpreter's XLA loop, named by its
+    # ``pallas_call(name=...)``: the device trace names its events so
+    assert re.search(
+        rf"%{name}(\.\d+)? = \S+ custom-call\(.*custom_call_target=\"tpu_custom_call\"",
+        compiled.as_text(),
+    ), name
     return compiled
 
 
@@ -92,6 +97,7 @@ def test_spmm_edge_tile_compiles(one_chip, batched):
     lead = (BATCH,) if batched else ()
     fn = functools.partial(spmm_edge_tile_pallas, slabs_per_block=spb, interpret=False)
     _compile(
+        "spmm_edge_tile",
         _batched(fn, (None, None, 0), batched),
         slabs,
         slabs,
@@ -106,6 +112,7 @@ def test_spmm_block_compiles(one_chip, batched):
     lead = (BATCH,) if batched else ()
     fn = functools.partial(spmm_block_pallas, num_row_blocks=N_PAD // 128, interpret=False)
     _compile(
+        "spmm_block",
         _batched(fn, (None, None, None, 0), batched),
         s((nb,), jnp.int32),
         s((nb,), jnp.int32),
@@ -125,6 +132,7 @@ def test_fused_count_compiles(one_chip, batched):
         fused_count_pallas, num_splits=SPLITS, slabs_per_block=spb, interpret=False
     )
     _compile(
+        "fused_count",
         _batched(fn, (None, None, 0, 0, None, None), batched),
         slabs,
         slabs,
@@ -141,4 +149,4 @@ def test_color_combine_compiles(one_chip, batched):
     idx = s((J_PAD, WIDTH), jnp.int32)
     table = s(((BATCH,) if batched else ()) + (N_PAD, WIDTH), jnp.float32)
     fn = functools.partial(color_combine_pallas, num_splits=SPLITS, interpret=False)
-    _compile(_batched(fn, (0, 0, None, None), batched), table, table, idx, idx)
+    _compile("color_combine", _batched(fn, (0, 0, None, None), batched), table, table, idx, idx)

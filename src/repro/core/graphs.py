@@ -7,8 +7,8 @@ directed entry ``(v, u)``).
 Two layouts feed the compute kernels:
 
 * **expanded edges** ``(rows, cols)`` — one entry per directed edge, rows
-  nondecreasing (CSR order).  This is the input to the XLA segment-sum path
-  and to the Pallas gather kernel.
+  nondecreasing (CSR order).  ``ops.build_spmm_plan`` cuts the XLA path's
+  neighbor-list pieces and the Pallas kernels' edge slabs from it.
 * **edge tiles** — the same arrays padded to a multiple of the tile size
   ``s`` with a sentinel row.  This is the TPU realization of the paper's
   *neighbor-list partitioning* (§3.3): every tile is a bounded, uniform unit

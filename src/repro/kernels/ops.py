@@ -2,8 +2,8 @@
 
 Every op has three implementations selected by ``impl``:
 
-* ``"xla"`` — pure-jnp path (scatter/segment/einsum); the default off-TPU
-  and the semantics oracle (it *is* ``ref.py`` modulo padding plumbing).
+* ``"xla"`` — pure-jnp path (gather/scatter/einsum); the default off-TPU
+  and where a table is too large for a kernel's VMEM.
 * ``"pallas"`` — the Pallas kernel, compiled on TPU, ``interpret=True``
   elsewhere (so CPU tests execute the actual kernel body).
 * ``"auto"`` — per call: pallas on TPU backends when the kernel's VMEM
@@ -132,14 +132,16 @@ def pad_to(x: int, multiple: int) -> int:
 class SpmmPlan:
     """Static preprocessing of a graph for the neighbor-sum op.
 
-    ``kind``: 'edges' (XLA scatter / Pallas edge-tiled gather) or 'blocks'
+    ``kind``: 'edges' (XLA piece sums / Pallas edge-tiled gather) or 'blocks'
     (block-dense Pallas); ``"auto"`` at build time picks one from measured
     patch density.  All index arrays are np/jnp int32, padded; the sentinel
     row is ``n`` (< n_pad).
 
-    The 'edges' plan carries two layouts of the same edge list:
+    The 'edges' plan carries three layouts of the same edge list:
 
-    * flat ``rows``/``cols`` [E_pad] — XLA segment-sum path and oracles;
+    * flat ``rows``/``cols`` [E_pad] — the oracles' form;
+    * pieces ``piece_cols``/``piece_rows`` — the XLA neighbor sum's
+      (:func:`build_piece_layout`, :func:`piece_sum`);
     * slab ``slab_dst``/``slab_cols`` [NRB * slabs_per_block, tile_size] —
       the paper's bounded neighbor-list tasks (§3.3): slabs of exactly
       ``tile_size`` edges grouped under the ``row_tile``-row output block
@@ -164,6 +166,9 @@ class SpmmPlan:
     slab_dst: Optional[jax.Array] = None  # [NRB * spb, tile_size]
     slab_cols: Optional[jax.Array] = None  # [NRB * spb, tile_size]
     slabs_per_block: int = 0
+    # --- piece layout (kind == 'edges'); the bucket shapes are static ---
+    piece_cols: Tuple[jax.Array, ...] = ()  # per group [pieces, 2^j] source rows
+    piece_rows: Tuple[jax.Array, ...] = ()  # per group [pieces] destination rows
     tile_size: int = 128
     row_tile: int = 128
     #: measured edges per occupied 128x128 patch (set by kind='auto')
@@ -177,6 +182,9 @@ class SpmmPlan:
             a = getattr(self, name)
             if a is not None:
                 out[name] = int(a.size) * a.dtype.itemsize
+        for name in ("piece_cols", "piece_rows"):
+            if getattr(self, name):
+                out[name] = sum(int(a.size) * a.dtype.itemsize for a in getattr(self, name))
         return out
 
 
@@ -187,7 +195,7 @@ jax.tree_util.register_dataclass(
     SpmmPlan,
     data_fields=[
         "rows", "cols", "block_rows", "block_cols", "patches",
-        "written_mask", "slab_dst", "slab_cols",
+        "written_mask", "slab_dst", "slab_cols", "piece_cols", "piece_rows",
     ],
     meta_fields=[
         "kind", "n", "n_pad", "block_size", "slabs_per_block", "tile_size",
@@ -294,6 +302,42 @@ def build_bucket_tiles(
     return tile_dst, tile_srcs, tile_off
 
 
+def build_piece_layout(
+    rows: np.ndarray, cols: np.ndarray, n_pad: int, tile_size: int, *, sentinel_col: int
+) -> Tuple[Tuple[np.ndarray, ...], Tuple[np.ndarray, ...]]:
+    """The neighbor-sum layout of a (dst-sorted) edge list that needs no
+    scatter over edges: the paper's §3.3 neighbor-list partitioning.
+
+    Each vertex's neighbor list is cut into consecutive pieces of at most
+    ``tile_size`` edges, and the pieces are grouped by length padded to the
+    next power of two.  Returns, per group, shortest first, the pieces'
+    source rows ``[pieces, 2^j]`` (pad slots ``sentinel_col``, which must
+    name an all-zero source row) and their destination rows ``[pieces]``,
+    nondecreasing.  A vertex of degree ``d`` owns ``ceil(d / tile_size)``
+    pieces: one, unless it has more than ``tile_size`` neighbors.
+    """
+    deg = np.bincount(rows, minlength=n_pad).astype(np.int64)
+    start = np.zeros(n_pad, np.int64)
+    np.cumsum(deg[:-1], out=start[1:])
+    count = -(-deg // tile_size)
+    owner = np.repeat(np.arange(n_pad), count)  # each piece's vertex, in order
+    first = np.repeat(np.cumsum(count) - count, count)
+    rank = np.arange(len(owner)) - first  # the piece's place in its vertex's list
+    begin = start[owner] + rank * tile_size
+    length = np.minimum(deg[owner] - rank * tile_size, tile_size)
+    width = np.left_shift(1, np.frexp(length - 1)[1])  # next power of two
+    padded = np.append(cols, sentinel_col).astype(np.int32)  # pad slots read the end
+    piece_cols, piece_rows = [], []
+    for w in np.unique(width):
+        sel = np.flatnonzero(width == w)
+        slot = np.arange(w)
+        piece_cols.append(
+            padded[np.where(slot < length[sel, None], begin[sel, None] + slot, len(cols))]
+        )
+        piece_rows.append(owner[sel].astype(np.int32))
+    return tuple(piece_cols), tuple(piece_rows)
+
+
 def _build_slabs(
     rows: np.ndarray,
     cols: np.ndarray,
@@ -352,6 +396,12 @@ def build_spmm_plan(
             slab_dst, slab_cols, spb = _build_slabs(
                 np.asarray(rows), np.asarray(cols), n, n_pad, tile_size, row_tile
             )
+        with obs.span("plan.piece_layout"):
+            piece_cols, piece_rows = build_piece_layout(
+                np.asarray(rows), np.asarray(cols), n_pad, tile_size, sentinel_col=sentinel
+            )
+        obs.count("neighbor_sum.pieces", sum(len(p) for p in piece_cols))
+        obs.count("neighbor_sum.edge_slots", sum(p.size for p in piece_cols))
         return SpmmPlan(
             "edges",
             n,
@@ -362,6 +412,8 @@ def build_spmm_plan(
             slab_dst=jnp.asarray(slab_dst),
             slab_cols=jnp.asarray(slab_cols),
             slabs_per_block=spb,
+            piece_cols=tuple(jnp.asarray(p) for p in piece_cols),
+            piece_rows=tuple(jnp.asarray(p) for p in piece_rows),
             tile_size=tile_size,
             row_tile=row_tile,
             patch_density=density,
@@ -414,9 +466,9 @@ def expected_patch_density(n: int, e_directed: int, block: int = 128) -> float:
 
 
 #: bound, in elements, on the gathered ``[edges, B]`` intermediate of the
-#: XLA neighbor sums; longer edge lists are summed in chunks.  Unchunked,
-#: XLA materializes every gathered row (15 GB for a 2^20-vertex Graph500
-#: graph at B = 128).
+#: XLA neighbor sums; longer edge lists and piece groups are summed in
+#: chunks.  Unchunked, XLA materializes every gathered row (15 GB for a
+#: 2^20-vertex Graph500 graph at B = 128).
 XLA_GATHER_ELEMENTS = 1 << 26
 
 
@@ -456,6 +508,58 @@ def gather_scatter_add(
     return jax.lax.scan(step, acc0, (rows, cols))[0]
 
 
+#: ``table[idx]`` for ``[pieces, width, 1]`` row indices, and the scatter-add
+#: of ``[pieces, B]`` rows into a table by ``[pieces, 1]`` row indices
+_ROW_GATHER = jax.lax.GatherDimensionNumbers(
+    offset_dims=(2,), collapsed_slice_dims=(0,), start_index_map=(0,)
+)
+_ROW_SCATTER = jax.lax.ScatterDimensionNumbers(
+    update_window_dims=(1,), inserted_window_dims=(0,), scatter_dims_to_operand_dims=(0,)
+)
+
+
+def piece_sum(
+    table: jax.Array,
+    piece_cols: Tuple[jax.Array, ...],
+    piece_rows: Tuple[jax.Array, ...],
+    num_rows: int,
+) -> jax.Array:
+    """The neighbor sum over a piece layout (:func:`build_piece_layout`):
+    each piece is summed by a gather and a reduction over its slots, and
+    the ``[pieces, B]`` sums are added into ``[num_rows, B]`` by a scatter
+    over pieces, not edges.  The gathered ``[pieces, 2^j, B]``
+    intermediate is bounded by ``XLA_GATHER_ELEMENTS``: longer groups are
+    summed in chunks.  ``piece_cols`` index ``table``, whose rows named by
+    pads must be zero."""
+    b = table.shape[1]
+    in_bounds = jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS
+
+    def add(out, cols, rows):
+        sums = jax.lax.gather(
+            table, cols[:, :, None], _ROW_GATHER, (1, b), mode=in_bounds
+        ).sum(axis=1)
+        # rows are sorted but not declared so: on a TPU v5e the sorted
+        # scatter passes over the whole [num_rows, B] table on every call,
+        # 6 ms at 524,416 x 896, where one chunk's pieces take far less
+        return jax.lax.scatter_add(out, rows[:, None], sums, _ROW_SCATTER, mode=in_bounds)
+
+    out = pvary_like(jnp.zeros((num_rows, b), table.dtype), table)
+    for cols, rows in zip(piece_cols, piece_rows):
+        pieces, width = cols.shape
+        chunk = max(1, XLA_GATHER_ELEMENTS // (width * b))
+        steps = pieces // chunk if pieces > chunk else 0
+        if steps:
+
+            def step(i, out, cols=cols, rows=rows, chunk=chunk):
+                c = jax.lax.dynamic_slice_in_dim(cols, i * chunk, chunk)
+                return add(out, c, jax.lax.dynamic_slice_in_dim(rows, i * chunk, chunk))
+
+            out = jax.lax.fori_loop(0, steps, step, out)
+        if steps * chunk < pieces:
+            out = add(out, cols[steps * chunk :], rows[steps * chunk :])
+    return out
+
+
 def spmm(plan: SpmmPlan, table: jax.Array, impl: str = "auto") -> jax.Array:
     """Neighbor sum ``M[v] = sum_{(v,u) in E} table[u]``.
 
@@ -467,10 +571,7 @@ def spmm(plan: SpmmPlan, table: jax.Array, impl: str = "auto") -> jax.Array:
     impl = spmm_impl(plan, b, impl)
     if plan.kind == "edges":
         if impl == "xla":
-            # rows are dst-sorted, pads (the sentinel row n) last
-            return gather_scatter_add(
-                table, plan.cols, plan.rows, plan.n_pad, indices_are_sorted=True
-            )
+            return piece_sum(table, plan.piece_cols, plan.piece_rows, plan.n_pad)
         # edge-tiled kernel writes every output block (pad slabs contribute
         # zeros), so zero-degree rows come out correctly zeroed
         return spmm_edge_tile_pallas(
@@ -522,9 +623,8 @@ def spmm_compact(
     assert plan.kind == "edges", "spmm_compact needs the edge-slab layout"
     impl = spmm_impl(plan, table_c.shape[1], impl, table_rows=table_c.shape[0])
     if impl == "xla":
-        return gather_scatter_add(
-            table_c, jnp.take(inv, plan.cols), plan.rows, plan.n_pad, indices_are_sorted=True
-        )
+        cols = tuple(jnp.take(inv, c) for c in plan.piece_cols)
+        return piece_sum(table_c, cols, plan.piece_rows, plan.n_pad)
     return spmm_edge_tile_pallas(
         plan.slab_dst,
         jnp.take(inv, plan.slab_cols),

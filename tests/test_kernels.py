@@ -5,6 +5,7 @@ allclose against ref.py.
 """
 
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +13,7 @@ import numpy as np
 import pytest
 
 from repro.core import erdos_renyi, rmat
-from repro.core.graphs import edge_list
+from repro.core.graphs import edge_list, from_edges
 from repro.kernels import ops, ref
 from repro.kernels.color_combine import color_combine_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
@@ -128,6 +129,125 @@ class TestSpmmKernels:
         want = jax.ops.segment_sum(table[plan.cols], plan.rows, num_segments=plan.n_pad)
         got = ops.gather_scatter_add(table, plan.cols, plan.rows, plan.n_pad)
         np.testing.assert_allclose(got, want, rtol=1e-6)
+
+
+def _undirected(g):
+    rows, cols = edge_list(g)
+    return np.stack([rows[rows < cols], cols[rows < cols]], 1)
+
+
+def _star_with(n: int, hubs):
+    """Vertex ``i`` of ``hubs`` joined to ``hubs[i]`` leaves of its own; the
+    other vertices of ``n`` are isolated."""
+    edges, leaf = [], len(hubs)
+    for hub, degree in enumerate(hubs):
+        edges += [(hub, leaf + j) for j in range(degree)]
+        leaf += degree
+    assert leaf <= n
+    return from_edges(n, np.asarray(edges), "star")
+
+
+class TestPieceSum:
+    """The XLA neighbor sum over the piece layout: neighbor lists cut into
+    ``tile_size`` pieces, summed by gathers and reductions, the piece sums
+    placed by a scatter over pieces."""
+
+    GRAPHS = {
+        "rmat_skew8": lambda: rmat(300, 3000, skew=8, seed=6),
+        # a hub of 400 neighbors spans four pieces, three of them full
+        "star_hub": lambda: from_edges(
+            401, np.concatenate([np.stack([np.zeros(400, int), np.arange(1, 401)], 1),
+                                 _undirected(rmat(401, 300, skew=3, seed=1))]), "hub"),
+        # degrees exactly tile_size and tile_size + 1
+        "degree_tile_and_tile_plus_1": lambda: _star_with(300, [128, 129]),
+        # 199 of the 300 vertices have no edge
+        "isolated": lambda: _star_with(300, [3, 1, 40, 2, 50]),
+    }
+
+    @pytest.mark.parametrize(
+        "case",
+        ["rmat_skew8", "star_hub", "degree_tile_and_tile_plus_1", "isolated", "vmap",
+         "compact", "chunked"],
+    )
+    def test_matches_segment_sum(self, monkeypatch, case):
+        g = self.GRAPHS.get(case, self.GRAPHS["rmat_skew8"])()
+        if case == "chunked":  # 448 / width pieces a chunk: the wide groups loop, with tails
+            monkeypatch.setattr(ops, "XLA_GATHER_ELEMENTS", 128 * 7 * 64)
+        plan = ops.build_spmm_plan(*edge_list(g), g.n, kind="edges")
+        assert plan.tile_size == 128
+        rng = np.random.default_rng(7)
+        # small integers: every order of the same terms sums to the same float
+        tables = rng.integers(0, 10, (3, plan.n_pad, 128)).astype(np.float32)
+        tables[:, g.n :] = 0.0
+        want = np.stack([
+            np.asarray(ref.spmm_segment_ref(plan.rows, plan.cols, t, plan.n_pad - 1))[: g.n]
+            for t in tables
+        ])
+        spmm = jax.jit(lambda p, t: ops.spmm(p, t, impl="xla"))
+        if case == "vmap":
+            got = jax.jit(jax.vmap(lambda p, t: ops.spmm(p, t, impl="xla"), (None, 0)))(
+                plan, jnp.asarray(tables))
+        elif case == "compact":
+            # a frontier of every third row: the compact table holds those
+            # rows, then one zero slot, and the indirection maps the rest there
+            active = np.arange(0, g.n, 3)
+            tables[:, np.setdiff1d(np.arange(plan.n_pad), active)] = 0.0
+            want = np.stack([
+                np.asarray(ref.spmm_segment_ref(plan.rows, plan.cols, t, plan.n_pad - 1))[: g.n]
+                for t in tables
+            ])
+            inv = np.full(plan.n_pad, len(active), np.int32)
+            inv[active] = np.arange(len(active))
+            compact = np.concatenate([tables[:, active], np.zeros((3, 1, 128), np.float32)], 1)
+            got = np.stack([
+                np.asarray(ops.spmm_compact(plan, jnp.asarray(c), jnp.asarray(inv), impl="xla"))
+                for c in compact
+            ])
+        else:
+            got = np.stack([np.asarray(spmm(plan, jnp.asarray(t))) for t in tables])
+        np.testing.assert_array_equal(np.asarray(got)[:, : g.n], want)
+
+    def test_layout_cuts_lists_at_tile_size(self):
+        g = _star_with(700, [128, 129, 400, 5])
+        rows, cols = edge_list(g)
+        piece_cols, piece_rows = ops.build_piece_layout(rows, cols, 768, 128, sentinel_col=700)
+        widths = [c.shape[1] for c in piece_cols]
+        assert widths == sorted(widths) and all(w & (w - 1) == 0 and w <= 128 for w in widths)
+        owner = np.concatenate(piece_rows)
+        # pieces per vertex: ceil(degree / 128); leaves one each
+        assert np.bincount(owner, minlength=768)[:4].tolist() == [1, 2, 4, 1]
+        assert all(np.all(np.diff(r) >= 0) for r in piece_rows)
+        # every edge sits in exactly one slot, beside the pads
+        slots = np.concatenate([c.ravel() for c in piece_cols])
+        assert sorted(slots[slots != 700].tolist()) == sorted(cols.tolist())
+
+    @staticmethod
+    def _scatter_rows(fn, *args):
+        """Update rows (elements over the table width) of every scatter in
+        ``fn``'s lowered HLO, loop bodies included."""
+        txt = jax.jit(fn).lower(*args).as_text(dialect="hlo")
+        shapes = dict(re.findall(r"(\S+) = \w+\[([\d,]*)\]", txt))
+        out = []
+        for upd in re.findall(r" scatter\([^,()]+, [^,()]+, ([^,()]+)\)", txt):
+            out.append(math.prod(int(d) for d in shapes[upd.strip()].split(",")) // 128)
+        return out
+
+    @pytest.mark.parametrize("chunk_elements", [None, 128 * 64])
+    def test_no_scatter_over_edges(self, monkeypatch, chunk_elements):
+        """The in-core XLA neighbor sum scatters only piece sums: no scatter
+        in its program updates as many rows as the graph has edges."""
+        g = rmat(300, 3000, skew=8, seed=6)
+        plan = ops.build_spmm_plan(*edge_list(g), g.n, kind="edges")
+        edges = len(edge_list(g)[0])
+        table = jnp.zeros((plan.n_pad, 128), jnp.float32)
+        # the detector sees the edge scatter of the distributed path's sum
+        over_edges = self._scatter_rows(
+            lambda p, t: ops.gather_scatter_add(t, p.cols, p.rows, p.n_pad), plan, table)
+        assert max(over_edges) >= edges
+        if chunk_elements:
+            monkeypatch.setattr(ops, "XLA_GATHER_ELEMENTS", chunk_elements)
+        pieces = self._scatter_rows(lambda p, t: ops.spmm(p, t, impl="xla"), plan, table)
+        assert pieces and max(pieces) <= max(len(r) for r in plan.piece_rows) < edges / 4
 
 
 class TestRouting:

@@ -18,9 +18,11 @@ import pytest
 
 from repro import obs
 from repro.api import Counter
-from repro.core import erdos_renyi
+from repro.core import erdos_renyi, rmat
 from repro.core.count_engine import build_counting_plan, count_fn
+from repro.core.graphs import edge_list
 from repro.core.templates import path_tree, spider_tree
+from repro.kernels import ops
 
 
 @pytest.fixture
@@ -131,17 +133,34 @@ def test_column_counters_equal_the_plan_widths(recording, lane):
     rights = [nodes[i].right for i in _internal(plan.chain)]
     true = sum(math.comb(plan.k, nodes[r].size) for r in rights)
     snap = obs.snapshot()
+    pieces = plan.spmm_plan.piece_cols
     assert snap["counters"] == {
         "neighbor_sum.columns_true": true,
         "neighbor_sum.columns_stored": sum(plan.widths[r] for r in rights),
+        "neighbor_sum.pieces": sum(len(p) for p in pieces),
+        "neighbor_sum.edge_slots": sum(p.size for p in pieces),
     }
     # k = 7: every table is at most C(7, 3) = 35 columns, one lane block
     assert snap["counters"]["neighbor_sum.columns_stored"] == (
         true if lane == 1 else 128 * len(rights)
     )
     assert [s[0] for s in snap["spans"]] == [
-        "plan.from_edges", "plan.slab_layout", "plan.node_tables"
+        "plan.from_edges", "plan.slab_layout", "plan.piece_layout", "plan.node_tables"
     ]
+
+
+def test_plan_build_counts_the_piece_layout(recording):
+    """The piece layout's padding is counted where the plan is built: every
+    directed edge takes one slot, and a vertex one piece per 128 neighbors."""
+    g = rmat(400, 3000, skew=8, seed=2)
+    rows, cols = edge_list(g)
+    ops.build_spmm_plan(rows, cols, g.n, kind="edges", tile_size=128)
+    counters = obs.snapshot()["counters"]
+    degree = np.bincount(rows, minlength=g.n)
+    assert counters["neighbor_sum.pieces"] == int(np.sum(-(-degree // 128)))
+    # each piece pads to the next power of two: under twice its edges
+    assert len(rows) <= counters["neighbor_sum.edge_slots"] < 2 * len(rows)
+    assert [s[0] for s in obs.snapshot()["spans"]][-2:] == ["plan.slab_layout", "plan.piece_layout"]
 
 
 def test_sample_stream_spans_the_host_turn(recording):

@@ -50,6 +50,7 @@ def readings(spec, seeds, *, controls=CONTROLS, require_tpu: bool = True):
         key = run.seed_key(seed, run.WINDOW)
         stream = cell.counter.sample_stream(key, batch=cell.batch)
         windows[seed] = (key, np.asarray([next(stream) for _ in range(steps)], np.float64))
+    del stream  # it holds the Counter, and with it the plan on the chips
     run.free_program(cell)
     out = []
     for seed, (key, answers) in windows.items():

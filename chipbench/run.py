@@ -4,11 +4,14 @@
 
 In order: the device check (the first device must be a TPU and there must be
 as many as the cell asks for, or the run exits 2 and prints no result), the
-configuration's graph, the program's ``Counter`` and its plan, the largest
-coloring batch that fits 3/4 of HBM, a warm-up of that batch, the measured
-window over ``Counter.sample_stream``, and the check of the answers the window
-produced against the plain reference (``reference.py``).  ``--trace 1`` traces
-the window with the JAX profiler and reports the per-layer metrics.
+configuration's graph, the program's ``Counter`` on the configuration's
+backend (``single``: one chip; ``distributed``: the graph sharded over the
+cell's chips) and its plan, the largest coloring batch that fits 3/4 of each
+chip's HBM, a warm-up of that batch, the measured window over
+``Counter.sample_stream``, and the check of the answers the window produced
+against the plain reference (``reference.py``), on one chip once the
+program's state is gone from all of them.  ``--trace 1`` traces the window
+with the JAX profiler and reports the per-layer metrics.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1`` also
@@ -26,6 +29,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import concurrent.futures  # noqa: E402
 import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
@@ -154,11 +158,39 @@ def device_arrays(obj, depth: int = 3):
 
 def program_bytes(counter, key, batch: int) -> int:
     """Bytes the compiled batch program needs beside its arguments, by
-    ``memory_analysis()`` (temporaries, outputs and code)."""
-    from repro.core.count_engine import count_fn
+    ``memory_analysis()`` (temporaries, outputs and code); for a sharded
+    program, those of one device."""
+    if counter.backend == "single":
+        from repro.core.count_engine import count_fn
 
-    m = count_fn(counter.plan, batch=batch).lower(key).compile().memory_analysis()
+        compiled = count_fn(counter.plan, batch=batch).lower(key).compile()
+    else:
+        import jax
+
+        program, arrays = distributed_program(counter)
+        keys = jax.random.key_data(jax.random.split(key, batch)).astype(np.uint32)
+        compiled = program.lower(keys, *arrays).compile()
+    m = compiled.memory_analysis()
     return m.temp_size_in_bytes + m.output_size_in_bytes + m.generated_code_size_in_bytes
+
+
+def distributed_program(counter):
+    """The jitted program behind a distributed ``Counter.sample_fn`` and the
+    placed plan arrays it is called with: ``keyed_sample_fn``'s count
+    function ``f`` calls ``run``, which calls ``shard_args(keys, *arrays)``.
+    Lowering ``f`` itself would embed the plan arrays in the program as
+    constants, so the harness lowers ``shard_args`` with the arrays as
+    arguments, as ``sample_fn`` calls it."""
+    import inspect
+
+    try:
+        f = inspect.getclosurevars(counter.sample_fn).nonlocals["f"]
+        inner = inspect.getclosurevars(inspect.getclosurevars(f).nonlocals["run"]).nonlocals
+        return inner["shard_args"], inner["arrays"]
+    except KeyError as missing:
+        raise SystemExit(
+            f"the distributed Counter's sample_fn no longer has the closures the harness "
+            f"lowers (sample_fn -> f -> run -> shard_args, arrays): {missing} not found") from None
 
 
 def largest_batch(counter, key, budget: int, resident: int, cap: int):
@@ -198,6 +230,11 @@ def coloring_of(backend: str, key, batch: int, slot: int, n: int, n_pad: int, k:
 
     if backend == "single":
         return jax.random.randint(key, (batch, n_pad), 0, k, dtype=jnp.int32)[slot, :n]
+    if backend == "distributed":
+        # one key per slot (the cell's mesh has no iteration axis to round
+        # the batch up to); a slot's coloring depends on (key, n, k) alone
+        return jax.random.randint(jax.random.split(key, batch)[slot], (n,), 0, k,
+                                  dtype=jnp.int32)
     raise ValueError(f"no coloring rule for the {backend!r} backend")
 
 
@@ -233,21 +270,31 @@ def build(spec, key, *, require_tpu: bool = True):
     plan = counter.plan
     jax.block_until_ready(device_arrays(plan))
     t_plan = time.perf_counter() - t0
-    say(f"[plan] built and placed in {t_plan:.3f}s; n_pad {plan.n_pad}")
+    # the single backend's coloring stream draws n_pad colors a slot; the
+    # distributed one draws n, whatever the shards' padding
+    n_pad = getattr(plan, "n_pad", None)
+    if n_pad is not None:
+        say(f"[plan] built and placed in {t_plan:.3f}s; n_pad {n_pad}")
+    else:
+        say(f"[plan] built and placed in {t_plan:.3f}s; {plan.num_shards} shards of "
+            f"{plan.shard_size} vertices, n_loc_pad {plan.n_loc_pad}")
 
-    # batch: the largest that fits 3/4 of HBM
+    # batch: the largest whose program fits 3/4 of each chip's HBM, beside
+    # what the fullest chip holds
     t0 = time.perf_counter()
-    stats = dev.memory_stats() or {}
-    if "bytes_limit" in stats:
-        batch, need, per = largest_batch(counter, key, int(stats["bytes_limit"] * HBM_SHARE),
-                                         stats["bytes_in_use"], traffic["batch_cap"])
-        say(f"[batch] {batch}: {need / 2**30:.3f} GiB of {stats['bytes_limit'] / 2**30:.3f}"
+    stats = [d.memory_stats() or {} for d in devs]
+    if all("bytes_limit" in st for st in stats):
+        limit = min(st["bytes_limit"] for st in stats)
+        batch, need, per = largest_batch(counter, key, int(limit * HBM_SHARE),
+                                         max(st["bytes_in_use"] for st in stats),
+                                         traffic["batch_cap"])
+        say(f"[batch] {batch}: {need / 2**30:.3f} GiB of {limit / 2**30:.3f}"
             f" GiB; {per / 2**30:.3f} GiB per further coloring")
     else:  # a device that reports no memory: the tests' CPU runs
         batch, need, per = traffic["batch_cap"], 0, None
     t_batch = time.perf_counter() - t0
     return types.SimpleNamespace(
-        devs=devs, n=n, edges=edges, counter=counter, n_pad=plan.n_pad, batch=batch,
+        devs=devs, n=n, edges=edges, counter=counter, n_pad=n_pad, batch=batch,
         per_coloring_bytes=per, need_bytes=need, backend=config["backend"],
         setup={"generate_s": t_gen, "plan_build_s": t_plan, "batch_s": t_batch},
     )
@@ -338,7 +385,12 @@ def run_cell(spec, seed: int, seconds: float, trace: bool, *, require_tpu: bool 
                 break
         t_close = time.perf_counter()
     if trace:
+        t0 = time.perf_counter()
         jax.profiler.stop_trace()
+        say(f"[trace] stopped and written in {time.perf_counter() - t0:.3f}s")
+        # the host reads the trace while the reference keeps the chip busy
+        reader = concurrent.futures.ThreadPoolExecutor(1)
+        reading = reader.submit(read_trace, log_dir)
     in_window = compiles.programs - compiled_before
     done = batch * len(answers)
     rate = done / (t_close - t_open)
@@ -385,9 +437,8 @@ def run_cell(spec, seed: int, seconds: float, trace: bool, *, require_tpu: bool 
                    "count": len(cell.devs), "memory_peak_bytes": int(peak)},
     }
     if trace:
-        import trace_reduce
-
-        red = trace_reduce.reduce_trace(trace_reduce.load(trace_reduce.find_trace(log_dir)))
+        red = reading.result()
+        reader.shutdown()
         if not trace_dir:
             shutil.rmtree(log_dir, ignore_errors=True)
         busy = sum(red["busy_s"]) / len(red["busy_s"])
@@ -403,10 +454,12 @@ def run_cell(spec, seed: int, seconds: float, trace: bool, *, require_tpu: bool 
             "peak": peaks(dev.device_kind) if require_tpu else None,
             "trace": red,
         }
+        t0 = time.perf_counter()
         for m in spec["per_layer"]:
             value = importlib.import_module("metrics." + m["name"]).read(context)
             if value is not None:
                 result["metrics"][m["name"]] = {"value": value, "unit": m["unit"]}
+        say(f"[trace] per-layer metrics read in {time.perf_counter() - t0:.3f}s")
     else:
         measured = {"colorings_per_s": rate, "setup_s": setup_s}
         for m in spec["end_to_end"]:
@@ -416,6 +469,18 @@ def run_cell(spec, seed: int, seconds: float, trace: bool, *, require_tpu: bool 
         "bad_answers": {"value": bad_answers, "limit": 0},
     }
     return result
+
+
+def read_trace(log_dir: str):
+    """The window's trace, reduced by ``trace_reduce``."""
+    import trace_reduce
+
+    t0 = time.perf_counter()
+    path = trace_reduce.find_trace(log_dir)
+    red = trace_reduce.reduce_trace(trace_reduce.load(path))
+    say(f"[trace] {os.path.getsize(path) / 2**20:.1f} MiB read and reduced in "
+        f"{time.perf_counter() - t0:.3f}s")
+    return red
 
 
 def peaks(kind: str):

@@ -10,7 +10,17 @@ its timed loop.  Within it, for every device plane (``/device:TPU:<i>``):
   over the window by ``op_name``;
 * idle gaps: the intervals of the window in which no operation runs, each
   named by the innermost harness span (``chipbench.*``) on the host that covers
-  its middle, or ``-`` where none does.
+  its middle, or ``-`` where none does;
+* exposed collective time: the time in which a collective operation runs
+  and no other operation but a control-flow one does
+  (``metrics/exchange_exposed_share.py``).
+
+A collective is an operation whose instruction name or opcode is
+``all-to-all``, ``collective-permute``, ``all-gather``, ``all-reduce`` or
+``reduce-scatter``, or one of their ``-start`` / ``-done`` halves.  The
+control-flow operations (``while``, ``conditional``, ``call``) are left out of
+what hides a collective, since their events span the operations of their
+bodies.
 
 Per chip numbers are averaged over the device planes.
 """
@@ -18,6 +28,7 @@ Per chip numbers are averaged over the device planes.
 from __future__ import annotations
 
 import collections
+import functools
 import glob
 import os
 import re
@@ -26,6 +37,9 @@ from typing import Dict, List, Tuple
 WINDOW_SPAN = "chipbench.window"
 SPAN_PREFIX = "chipbench."
 OPS_LINE = "XLA Ops"
+COLLECTIVE = re.compile(
+    r"(all-to-all|collective-permute|all-gather|all-reduce|reduce-scatter)(-start|-done)?$")
+CONTAINERS = frozenset({"while", "conditional", "call"})
 
 
 def find_trace(log_dir: str) -> str:
@@ -46,6 +60,7 @@ def _union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
     return merged
 
 
+@functools.lru_cache(maxsize=None)
 def op_name(hlo: str) -> str:
     """``%fusion.63 = f32[8,128]{1,0} fusion(...), kind=kLoop, ...`` ->
     ``fusion.63 fusion kLoop f32[8,128]``: the instruction's name, opcode,
@@ -68,6 +83,30 @@ def op_name(hlo: str) -> str:
     opcode = rest.strip().split("(", 1)[0]
     kind = re.search(r"kind=(\w+)", rest)
     return " ".join([m.group(1), opcode] + ([kind.group(1)] if kind else []) + [shape])
+
+
+@functools.lru_cache(maxsize=None)
+def op_kind(hlo: str) -> str:
+    """``collective``, ``container`` or ``other``, from the instruction's
+    name without its ``.<n>`` suffix and from its opcode."""
+    parts = op_name(hlo).split(" ")
+    names = (re.sub(r"\.\d+$", "", parts[0]), parts[1] if len(parts) > 1 else "")
+    if any(COLLECTIVE.match(x) for x in names):
+        return "collective"
+    return "container" if CONTAINERS.intersection(names) else "other"
+
+
+def exposed_collective_ns(ops) -> Tuple[float, int]:
+    """Nanoseconds of ``(name, start_ns, end_ns)`` ops in which a collective
+    runs and no other operation but a control-flow one does, and the number
+    of collective ops: what collectives and other ops cover together less
+    what the other ops cover."""
+    coll = [(s, e) for n, s, e in ops if op_kind(n) == "collective"]
+    if not coll:
+        return 0.0, 0
+    other = [(s, e) for n, s, e in ops if op_kind(n) == "other"]
+    both = sum(e - s for s, e in _union(coll + other))
+    return both - sum(e - s for s, e in _union(other)), len(coll)
 
 
 def host_spans(profile) -> List[Tuple[str, float, float]]:
@@ -121,7 +160,9 @@ def _label(spans, t: float) -> str:
 
 def reduce_trace(profile, top: int = 10) -> Dict[str, object]:
     """``window_s``, per-chip ``busy_s``, the ``top`` operations by seconds
-    (mean over chips) and the ``top`` longest idle gaps by host span."""
+    (mean over chips), the ``top`` longest idle gaps by host span, per chip
+    (in the order of ``busy_s``) its exposed collective seconds, and the
+    number of collective ops in the window over all chips."""
     spans = host_spans(profile)
     windows = [(s, e) for name, s, e in spans if name == WINDOW_SPAN]
     if not windows:
@@ -130,9 +171,12 @@ def reduce_trace(profile, top: int = 10) -> Dict[str, object]:
     planes = device_ops(profile)
     if not planes:
         raise ValueError("the trace holds no device operations")
-    busy, by_name, gaps = [], collections.Counter(), []
+    busy, by_name, gaps, exposed, collectives = [], collections.Counter(), [], [], 0
     for name in sorted(planes):
         ops = [(n, max(s, lo), min(e, hi)) for n, s, e in planes[name] if e > lo and s < hi]
+        ns, count = exposed_collective_ns(ops)
+        exposed.append(ns / 1e9)
+        collectives += count
         for n, sec in self_times(ops):
             by_name[op_name(n)] += sec / len(planes)
         merged = _union([(s, e) for _, s, e in ops])
@@ -148,6 +192,8 @@ def reduce_trace(profile, top: int = 10) -> Dict[str, object]:
         "chips": len(planes),
         "device_ops": [[n, s] for n, s in by_name.most_common(top)],
         "idle_gaps": [[n, s] for n, s in gaps[:top]],
+        "collective_exposed_s": exposed,
+        "collective_ops": collectives,
     }
 
 
